@@ -42,7 +42,7 @@ def analyze(arch: str, n_micro: int = 8):
               f"   intra {span['intra']/1e9:8.2f} GB/dev", flush=True)
 
     run = make_run(arch, "train_4k")
-    with mesh:
+    with jax.set_mesh(mesh):
         model = Model(run)
         fn, args, in_sh, out_sh = model.dryrun_case(mesh)
         record("dp", jax.jit(fn, in_shardings=in_sh,

@@ -103,6 +103,9 @@ class WorkerPool:
             wid = f"w{self._counter}"
         env = os.environ.copy()
         env[tasklib.WORKER_ENV] = wid
+        # the driver process owns the accelerator; a worker that imports
+        # jax must not try to take it
+        env["JAX_PLATFORMS"] = "cpu"
         path = env.get("PYTHONPATH", "")
         if _SRC_DIR not in path.split(os.pathsep):
             env["PYTHONPATH"] = (_SRC_DIR + os.pathsep + path) if path \

@@ -43,6 +43,7 @@ leave memoization off (``memoizable=False`` overrides a manager-wide
 """
 from __future__ import annotations
 
+import contextlib
 import pickle
 import threading
 import time
@@ -264,16 +265,20 @@ class MigrationManager:
         return fn
 
     def _capture_cost(self, step: Step, fn, kwargs):
-        """First-execution XLA cost analysis -> cost model stats."""
+        """First-execution XLA cost analysis -> cost model stats.
+
+        Returns what to call: on a jitted step's first execution, the
+        executable compiled here, whose cost analysis is read; later calls
+        find it in jit's own cache, so the step is compiled once."""
         st = self.cost_model.stats_for(step.name)
-        if st.flops or not step.jax_step:
-            return
-        try:
-            ca = fn.lower(**kwargs).compile().cost_analysis()
-            st.flops = float(ca.get("flops", 0.0))
-            st.bytes_accessed = float(ca.get("bytes accessed", 0.0))
-        except Exception:
-            pass
+        if st.flops or not hasattr(fn, "lower"):
+            return fn
+        compiled = fn.lower(**kwargs).compile()
+        # None where the backend offers no analysis
+        ca = compiled.cost_analysis() or {}
+        st.flops = float(ca.get("flops", 0.0))
+        st.bytes_accessed = float(ca.get("bytes accessed", 0.0))
+        return compiled
 
     # -------------------------------------------------------------- execute
     def execute(self, step: Step, tier_name: str, *, mdss=None,
@@ -439,13 +444,17 @@ class MigrationManager:
             bytes_in = wire_in
             remote, worker_pid, wire_bytes_out = True, pid, wire_out
         else:
-            fn = self._executable(step, tier_name)
-            self._capture_cost(step, fn, kwargs)
+            def on_tier():
+                return jax.set_mesh(tier.mesh) if tier.mesh is not None \
+                    else contextlib.nullcontext()
+
+            with on_tier():
+                fn = self._capture_cost(
+                    step, self._executable(step, tier_name), kwargs)
             t0 = time.perf_counter()
             with self.tracer.span("exec", cat="exec", step=step.name,
                                   tier=tier_name, remote=False):
-                ctx = tier.mesh if tier.mesh is not None else _nullcontext()
-                with ctx:
+                with on_tier():
                     out = fn(**kwargs)
                 out = jax.block_until_ready(out) if step.jax_step else out
             dt = time.perf_counter() - t0
@@ -534,11 +543,3 @@ class MigrationManager:
                 "on the fabric") from e
         return (out, task.seconds, task.bytes_sent, task.bytes_received,
                 task.worker_pid)
-
-
-class _nullcontext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False

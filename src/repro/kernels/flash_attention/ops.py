@@ -2,8 +2,8 @@
 
 Handles padding (seq to block multiples, head dims to 128 lanes) and the
 (B,S,H,d) <-> (B,H,S,d) transposes the kernel wants. The backward pass uses
-the jnp reference via ``jax.custom_vjp`` (flash recompute-style bwd kernel
-is future work; on this CPU container the ref path is what lowers anyway).
+the jnp reference via ``jax.custom_vjp`` (a flash recompute-style backward
+kernel is future work).
 """
 from __future__ import annotations
 
@@ -27,10 +27,7 @@ def _pad_to(x, axis, mult):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention_kernel_call(q, k, v, *, scale, causal=True, kv_len=None,

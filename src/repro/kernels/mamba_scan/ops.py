@@ -26,10 +26,7 @@ from repro.kernels.mamba_scan.kernel import selective_scan_fwd
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))

@@ -96,7 +96,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, slopes: bool = True,
         return rec
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             model, compiled = _compile_cell(run, mesh)
             base_cost = ha.cost_dict(compiled)
             base_coll = ha.collective_bytes(compiled.as_text())

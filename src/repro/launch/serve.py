@@ -40,6 +40,7 @@ from repro.configs import get_config
 from repro.configs.base import ModelConfig, RunConfig, ShapeProfile, reduced
 from repro.core import (CostModel, EmeraldExecutor, EmeraldRuntime, MDSS,
                         MigrationManager, Workflow, default_tiers, partition)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import Model
 
 INTERACTIVE = 1          # broker dispatch class for latency-bound decodes
@@ -242,10 +243,12 @@ class FrontDoor:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="CPU-sized same-family config")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch)) if args.reduced else get_config(args.arch)
     run = RunConfig(model=cfg, shape=ShapeProfile("serve", 128, 4, "decode"),

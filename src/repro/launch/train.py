@@ -29,6 +29,7 @@ from repro.configs.base import ModelConfig, RunConfig, ShapeProfile, reduced
 from repro.core import (CostModel, EmeraldExecutor, EmeraldRuntime, MDSS,
                         MigrationManager, Workflow, default_tiers, partition)
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import Model
 
 
@@ -155,6 +156,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

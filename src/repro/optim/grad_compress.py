@@ -2,8 +2,9 @@
 
 Multi-pod data parallelism pays its gradient all-reduce over the slow
 pod-to-pod links (DCI, ~25 GB/s vs 50 GB/s/link ICI in-pod). This module
-makes that reduction explicit — ``jax.shard_map`` manual over the ``pod``
-axis only, auto over (data, model) — so the wire format is controllable:
+makes that reduction explicit — ``jax.shard_map`` manual over every mesh
+axis, the (data, model) devices of a pod holding replicas — so the wire
+format is controllable:
 
   * ``none``  — plain psum (bf16 wire at param dtype; the pjit baseline),
   * ``bf16``  — cast to bf16 before the psum (2x vs fp32 grads),
@@ -38,8 +39,8 @@ def sync_grads(grads, axis_name: str, method: str = "none"):
 
     def bf16_(g):
         # all-gather keeps bf16 as the wire dtype; direct bf16 psum trips an
-        # XLA:CPU crash ("Invalid binary instruction opcode copy") under
-        # partial-manual shard_map, and ring-AR wire bytes are equivalent.
+        # XLA:CPU crash ("Invalid binary instruction opcode copy") inside
+        # shard_map, and ring-AR wire bytes are equivalent.
         gs = jax.lax.all_gather(g.astype(jnp.bfloat16), axis_name)
         return (jnp.sum(gs.astype(jnp.float32), axis=0) / n).astype(g.dtype)
 
@@ -57,9 +58,9 @@ def sync_grads(grads, axis_name: str, method: str = "none"):
 def multipod_train_step(model, mesh, method: str = "bf16"):
     """Wrap a Model's train step with explicit compressed cross-pod sync.
 
-    Requires a mesh with a ``pod`` axis. Params/opt-state are replicated
-    across pods (their data/model sharding stays with the auto axes);
-    the batch is split across pods; each pod computes local gradients, the
+    Requires a mesh with a ``pod`` axis. Params/opt-state are replicated;
+    the batch is split across pods, and the devices of one pod compute its
+    share as replicas; each pod computes local gradients, the
     compressed sync averages them, and every pod applies the identical
     update.
     """
@@ -69,10 +70,9 @@ def multipod_train_step(model, mesh, method: str = "bf16"):
     from repro.optim.optimizers import clip_by_global_norm
 
     assert "pod" in mesh.shape, "multipod_train_step needs a 'pod' axis"
-    cfg, run, rules = model.cfg, model.run, dict(model.rules)
-    # inside the manual-pod region, activation constraints must not
-    # reference the pod axis
-    rules["act_batch"] = ("data",)
+    cfg, run = model.cfg, model.run
+    # every mesh axis is manual in the region: no activation constraints
+    rules = {k: v for k, v in model.rules.items() if not k.startswith("act_")}
     opt_update, schedule = model.opt_update, model.schedule
 
     def per_pod(params, opt_state, batch):
@@ -92,4 +92,4 @@ def multipod_train_step(model, mesh, method: str = "bf16"):
         per_pod, mesh=mesh,
         in_specs=(P(), P(), P("pod")),
         out_specs=(P(), P(), P()),
-        axis_names={"pod"}, check_vma=False)
+        check_vma=False)

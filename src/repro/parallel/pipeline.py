@@ -7,7 +7,8 @@ per step; *pipeline* parallelism over pods costs only boundary activations
 module provides the PP alternative so the cross-pod axis can be chosen per
 model (see EXPERIMENTS.md §Perf multi-pod analysis).
 
-Mechanics (partial-manual ``shard_map`` over ``pod``; auto over data/model):
+Mechanics (``shard_map`` manual over every mesh axis; the data/model axes
+hold replicas inside the region, so activation rules are emptied there):
 
   * each LM stage's stacked layer params shard their leading (layers) dim
     over ``pod`` — pod *p* owns a contiguous slice of layers,
@@ -47,8 +48,8 @@ def pipeline_train_step(model, mesh, n_micro: int) -> Callable:
     assert "pod" in mesh.shape
     n_stages = mesh.shape["pod"]
     cfg, run = model.cfg, model.run
-    rules = dict(model.rules)
-    rules["act_batch"] = ("data",)          # pod axis is manual here
+    # every mesh axis is manual in the region: no activation constraints
+    rules = {k: v for k, v in model.rules.items() if not k.startswith("act_")}
     opt_update, schedule = model.opt_update, model.schedule
     stages = cfg.stages()
     assert not cfg.is_encoder_decoder, "PP path covers decoder-only archs"
@@ -100,8 +101,8 @@ def pipeline_train_step(model, mesh, n_micro: int) -> Callable:
             name = path[0].key if path else ""
             if name.startswith("stage_"):
                 return g
-            # f32 cast: direct bf16 psum trips an XLA:CPU crash under
-            # partial-manual shard_map (same bug as grad_compress.py)
+            # f32 cast: direct bf16 psum trips an XLA:CPU crash inside
+            # shard_map (same bug as grad_compress.py)
             return jax.lax.psum(g.astype(jnp.float32), "pod").astype(g.dtype)
         grads = jax.tree_util.tree_map_with_path(sync_replicated, grads)
         grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
@@ -125,4 +126,4 @@ def pipeline_train_step(model, mesh, n_micro: int) -> Callable:
         per_pod, mesh=mesh,
         in_specs=(p_specs, o_specs, P()),
         out_specs=(p_specs, o_specs, P()),
-        axis_names={"pod"}, check_vma=False)
+        check_vma=False)

@@ -155,17 +155,6 @@ def constrain(x, rules: Rules, *names: Optional[str]):
 
 
 def get_abstract_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            # physical mesh if inside a `with mesh:` context
-            pm = getattr(m, "_raw_mesh", None)
-            return pm if pm is not None else m
-    except Exception:
-        pass
-    import warnings
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m

@@ -16,13 +16,6 @@ from repro.checkpoint import Checkpointer
 # still part of tier-1 (plain pytest runs everything)
 pytestmark = pytest.mark.slow
 
-# The explicit-mesh API (jax.sharding.AxisType / jax.set_mesh) is newer
-# than this container's jax; the subprocess scripts below require it.
-import jax as _jax
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(_jax.sharding, "AxisType"),
-    reason="installed jax lacks jax.sharding.AxisType (explicit-mesh API)")
-
 
 def tree():
     return {"params": {"w": jnp.arange(12.0).reshape(3, 4),
@@ -105,7 +98,6 @@ ELASTIC_SCRIPT = textwrap.dedent("""
 """)
 
 
-@needs_axis_type
 def test_elastic_reshard_across_meshes(tmp_path):
     """Save sharded on a (4,2) mesh, restore onto a (2,4) mesh."""
     src = os.path.join(os.path.dirname(__file__), "..", "src")

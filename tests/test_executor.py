@@ -200,3 +200,36 @@ def test_cost_model_policy_offloads_heavy_steps():
     ex = EmeraldExecutor(partition(wf), emerald(), policy="cost_model")
     ex.run({"x": jnp.float32(1.0)})
     assert any(e.kind == "offload" for e in ex.events)
+
+
+def test_jax_step_compiles_once_and_records_cost():
+    """The first execution's cost analysis reuses the program the step
+    runs: two runs of one jitted step compile it once."""
+    from jax import monitoring
+    compiles = []
+
+    def on_duration(event, secs, fun_name="", **_):
+        if event == "/jax/core/compile/backend_compile_duration" \
+                and "compile_once_step" in fun_name:
+            compiles.append(fun_name)
+
+    def compile_once_step(x):
+        return {"y": jnp.sin(x) @ x}
+
+    wf = Workflow("once")
+    wf.var("x")
+    wf.step("s", compile_once_step, inputs=("x",), outputs=("y",),
+            remotable=True)
+    mgr = emerald()
+    ex = EmeraldExecutor(partition(wf), mgr, policy="annotate")
+    xs = [jnp.full((16, 16), float(i)) for i in range(2)]
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        outs = [ex.run({"x": x})["y"] for x in xs]
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    for x, y in zip(xs, outs):
+        np.testing.assert_allclose(np.asarray(y),
+                                   np.asarray(jnp.sin(x) @ x), rtol=1e-6)
+    assert len(compiles) == 1, compiles
+    assert mgr.cost_model.stats_for("s").flops > 0

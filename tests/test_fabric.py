@@ -95,6 +95,17 @@ def test_step_runs_in_separate_process(fabric):
     assert int(out["pid"]) in fabric.broker.worker_pids()
 
 
+def test_worker_never_takes_the_accelerator(monkeypatch):
+    """Workers run jax on the CPU whatever the driver's platform: the
+    driver process holds the chip, and a second process cannot take it."""
+    import pickle
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with Fabric(workers=1) as f:
+        out = f.broker.submit(fn_bytes=pickle.dumps(os.getenv),
+                              kwargs={"key": "JAX_PLATFORMS"}).result(30)
+    assert out == "cpu"
+
+
 def test_ship_moves_real_bytes(fabric):
     val = {"a": np.random.rand(1 << 12).astype(np.float32)}
     task = fabric.ship(val)

@@ -10,13 +10,6 @@ import textwrap
 
 import pytest
 
-# The explicit-mesh API (jax.sharding.AxisType / jax.set_mesh) is newer
-# than this container's jax; the subprocess scripts below require it.
-import jax as _jax
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(_jax.sharding, "AxisType"),
-    reason="installed jax lacks jax.sharding.AxisType (explicit-mesh API)")
-
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -67,7 +60,6 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
-@needs_axis_type
 @pytest.mark.slow
 def test_multipod_compressed_sync_subprocess():
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -1,5 +1,6 @@
 """Per-kernel allclose vs the pure-jnp oracle, swept over shapes/dtypes
-(interpret mode — this container is CPU-only; TPU is the target)."""
+(Pallas interpret mode; tests/test_tpu_compile.py compiles the kernels for
+the TPU at real widths)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

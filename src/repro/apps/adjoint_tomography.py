@@ -127,36 +127,37 @@ def true_model(cfg: ATConfig) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# The four AT steps (paper §4), as workflow step functions.
+# The four AT steps (paper §4), as workflow step functions. Each closure
+# bears its step's name, which jit gives its program (``jit_forward``...).
 # ---------------------------------------------------------------------------
 
 def step_forward(cfg: ATConfig):
-    def fn(model):
+    def forward(model):
         return {"syn": simulate(model, cfg)}
-    return fn
+    return forward
 
 
 def step_misfit(cfg: ATConfig):
-    def fn(syn, obs):
+    def misfit(syn, obs):
         r = syn - obs
         return {"chi": 0.5 * jnp.sum(r * r)}
-    return fn
+    return misfit
 
 
 def step_kernel(cfg: ATConfig):
-    def fn(model, obs):
+    def kernel(model, obs):
         def chi_of(m):
             r = simulate(m, cfg) - obs
             return 0.5 * jnp.sum(r * r)
         return {"grad": jax.grad(chi_of)(model)}
-    return fn
+    return kernel
 
 
 def step_update(cfg: ATConfig):
-    def fn(model, grad):
+    def update(model, grad):
         g = grad / (jnp.max(jnp.abs(grad)) + 1e-20)
         return {"model": model - cfg.lr * g * 20.0}
-    return fn
+    return update
 
 
 def _sim_flops(cfg: ATConfig) -> float:

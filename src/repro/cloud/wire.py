@@ -198,6 +198,23 @@ def _strip(obj, buffers: List[memoryview]):
     return obj
 
 
+def to_host(obj):
+    """``obj`` with every foreign (device) array leaf copied to a numpy
+    array, in the same containers ``_strip`` walks: the host copy that
+    stripping would make, taken as a step of its own. Stripping the result
+    gives the same skeleton and bytes as stripping ``obj``."""
+    if _is_foreign_array(obj):
+        return np.asarray(obj)
+    if isinstance(obj, dict):
+        return {k: to_host(v) for k, v in obj.items()}
+    if isinstance(obj, tuple):
+        vals = [to_host(v) for v in obj]
+        return type(obj)(*vals) if hasattr(obj, "_fields") else tuple(vals)
+    if isinstance(obj, list):
+        return [to_host(v) for v in obj]
+    return obj
+
+
 def _fill(obj, buffers: List[Any]):
     if isinstance(obj, _Buf):
         try:

@@ -69,7 +69,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.cloud.wire import manifest_of
+from repro.cloud.wire import manifest_of, to_host
+from repro.obs.tracing import Tracer
 
 
 class MDSSTransferError(RuntimeError):
@@ -206,6 +207,10 @@ class MDSS:
             OrderedDict()
         self.manifest_cache_cap = 4096
         self.dedup_bytes_elided: int = 0   # transfer bytes chunk-dedup saved
+        self.bytes_hashed: int = 0         # bytes put through _manifest
+        # disabled by default; an owning runtime swaps in its live tracer
+        # so the d2h and hash phases of every manifest reach the profiler
+        self.tracer = Tracer(enabled=False)
 
     # ------------------------------------------------------------------ api
     def put(self, uri: str, value, tier: str = "local",
@@ -222,7 +227,7 @@ class MDSS:
         if _manifest is None and self.chunk_dedup:
             # hash before taking the lock (re-entrant callers that
             # already hold it pay under the lock, same as before)
-            _manifest = manifest_of(value)
+            _manifest = self._manifest(value)
         with self._lock:
             e = self._entries.setdefault(uri, _Entry())
             if expect_version is not None and e.version != expect_version:
@@ -239,7 +244,7 @@ class MDSS:
         """Hash a batch's manifests with NO lock held (for put_many)."""
         if not self.chunk_dedup:
             return {}
-        return {uri: manifest_of(val) for uri, val in values.items()}
+        return {uri: self._manifest(val) for uri, val in values.items()}
 
     def put_many(self, values: Dict[str, Any], tier: str = "local",
                  expect_versions: Optional[Dict[str, int]] = None):
@@ -517,6 +522,21 @@ class MDSS:
         self._last_used[(uri, tier)] = next(self._use_tick)
 
     # ------------------------------------------------- content addressing
+    def _manifest(self, value):
+        """The one place the store hashes: ``value``'s device arrays copied
+        to the host (phase ``d2h``), then its chunk manifest
+        (phase ``hash``), counted in ``bytes_hashed``. ``manifest_of`` is
+        looked up as this module's global at each call, so a wrapper
+        installed there sees every hash."""
+        n = nbytes_of(value)
+        with self.tracer.phase("d2h", bytes=n):
+            host = to_host(value)
+        with self.tracer.phase("hash", bytes=n):
+            mani = manifest_of(host)
+        with self._lock:
+            self.bytes_hashed += n
+        return mani
+
     def _manifest_for(self, uri: str, version: int, value):
         """(content_digest, [(chunk_digest, length), ...]) of a stored
         value, cached per (uri, version) — lock held. Hashing happens
@@ -528,7 +548,7 @@ class MDSS:
         if got is not None:
             self._manifest_cache.move_to_end(key)
             return got
-        mani = manifest_of(value)
+        mani = self._manifest(value)
         self._cache_manifest(key, mani)
         return mani
 
@@ -560,7 +580,7 @@ class MDSS:
                     todo.append((uri, version, value))
         if not todo:
             return
-        hashed = [(u, v, manifest_of(val)) for u, v, val in todo]
+        hashed = [(u, v, self._manifest(val)) for u, v, val in todo]
         with self._lock:
             for u, v, mani in hashed:
                 if (u, v) not in self._manifest_cache:
@@ -869,6 +889,7 @@ class MDSS:
         registry.gauge("mdss.eviction_bytes", lambda: self.eviction_bytes)
         registry.gauge("mdss.dedup_bytes_elided",
                        lambda: self.dedup_bytes_elided)
+        registry.gauge("mdss.bytes_hashed", lambda: self.bytes_hashed)
         registry.gauge("mdss.entries", lambda: len(self._entries))
         registry.gauge("mdss.chunk_index_bytes", self._chunk_index_bytes)
 
@@ -985,7 +1006,7 @@ class NamespacedMDSS:
             if self.version(uri) != expect_version:
                 self.base.fenced_puts += 1
                 return None
-        mani = manifest_of(value) if self.base.chunk_dedup else None
+        mani = self.base._manifest(value) if self.base.chunk_dedup else None
         with self.base._lock:
             if self.version(uri) != expect_version:
                 self.base.fenced_puts += 1

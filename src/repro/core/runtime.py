@@ -65,11 +65,13 @@ with MDSS-side LRU eviction.
 """
 from __future__ import annotations
 
+import ctypes
 import heapq
 import itertools
 import os
 import pickle
 import queue
+import sys
 import threading
 import time
 import warnings
@@ -402,6 +404,25 @@ def _park_order(p: _Parked) -> tuple:
 
 
 _AUTO = object()
+_PR_SET_NAME = 15                  # prctl: name the calling thread
+
+
+def _name_os_thread(role: str):
+    """Give the calling thread ``role`` (at most 15 bytes kept) as its OS
+    thread name on Linux: a profiler names its host rows by it."""
+    if sys.platform != "linux":
+        return
+    try:
+        ctypes.CDLL(None).prctl(_PR_SET_NAME, role.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass
+
+
+def _pool(workers: int, role: str, name: str) -> ThreadPoolExecutor:
+    role = f"emerald-{role}"
+    return ThreadPoolExecutor(
+        max_workers=workers, thread_name_prefix=f"{role}:{name}",
+        initializer=_name_os_thread, initargs=(role,))
 
 
 # --------------------------------------------------------------------------
@@ -440,7 +461,12 @@ class EmeraldRuntime:
             enabled=telemetry)
         self.metrics = metrics if metrics is not None else MetricsRegistry(
             enabled=telemetry)
+        if self.tracer.enabled and self.tracer.annotation is None:
+            # the tracer's second sink: every span it opens is also a
+            # profiler host annotation, on the profiler's clock
+            self.tracer.annotation = jax.profiler.TraceAnnotation
         manager.tracer = self.tracer
+        self.mdss.tracer = self.tracer
         manager.register_metrics(self.metrics)
         self.mdss.register_metrics(self.metrics)
         self.default_policy = policy
@@ -514,22 +540,20 @@ class EmeraldRuntime:
         m.gauge("frontdoor.parked_total", lambda: self.parked_total)
         m.gauge("frontdoor.admitted_total", lambda: self.admitted_total)
 
-        self._offload_pool = ThreadPoolExecutor(
-            max_workers=max_workers, thread_name_prefix=f"{name}-offload")
-        self._local_pool = ThreadPoolExecutor(
-            max_workers=local_workers, thread_name_prefix=f"{name}-local")
+        # threads are named "emerald-<role>:<runtime name>"; the role
+        # alone is also their OS name, which a profiler's host rows show
+        self._offload_pool = _pool(max_workers, "offload", name)
+        self._local_pool = _pool(local_workers, "local", name)
         # re-integration fetches run here so a slow cloud->local sync
         # never stalls the driver (and with it every other run's dispatch)
-        self._misc_pool = ThreadPoolExecutor(
-            max_workers=2, thread_name_prefix=f"{name}-finalize")
+        self._misc_pool = _pool(2, "finalize", name)
         # dedicated checkpoint writer lane: pickle writes must never
         # serialise the driver loop (one slow-disk tenant would stall
         # every other run's dispatch); one thread keeps per-run write
         # order trivially FIFO
-        self._ckpt_pool = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix=f"{name}-ckpt")
+        self._ckpt_pool = _pool(1, "ckpt", name)
         self._driver = threading.Thread(target=self._drive, daemon=True,
-                                        name=f"{name}-driver")
+                                        name=f"emerald-driver:{name}")
         self._driver.start()
 
     # ------------------------------------------------------------------ api
@@ -601,74 +625,82 @@ class EmeraldRuntime:
             raise ValueError(
                 "resume=True needs the namespace of the run being resumed "
                 "(auto namespaces are fresh per submission)")
-        pwf = workflow if isinstance(workflow, PartitionedWorkflow) \
-            else partition(workflow)
-        wf = pwf.workflow
+        wf_name = workflow.workflow.name \
+            if isinstance(workflow, PartitionedWorkflow) else workflow.name
         n = next(self._counter)
-        run_id = f"{wf.name}#{n}"
-        ns = f"run{n}" if namespace is None else namespace
-        mdss = self.mdss if ns == "" else self.mdss.namespaced(
-            ns, shared=self.shared_namespace)
-        if residency_budget and not ns:
-            raise ValueError(
-                "residency_budget needs a namespaced run (an "
-                "un-namespaced submission shares the base store)")
-        declared = sum(residency_budget.values()) if residency_budget else 0
-        deadline_perf = None if deadline_s is None \
-            else time.perf_counter() + deadline_s
-        limit = self.admission_headroom * self.mdss.capacity_bytes \
-            if declared and self.mdss.capacity_bytes else None
-        with self._runs_lock:
-            if self.max_active_runs is not None \
-                    and self._live >= self.max_active_runs:
-                if not park:
-                    raise AdmissionRefused(
-                        f"{self._live} of {self.max_active_runs} run slots "
-                        "busy: submission refused")
-                park_reason = park_reason or "run_slots"
-            if limit is not None and park_reason is None:
-                # check + reserve atomically: two concurrent submits that
-                # each fit alone but not together must not both pass. An
-                # admitted run's unfilled declared budget is capacity it
-                # may still legitimately consume.
-                reserved = sum(
-                    max(0, decl - self.mdss.namespace_resident_bytes(rns))
-                    for rns, decl in self._reserved.values())
-                committed = self.mdss.resident_bytes() + reserved
-                if committed + declared > limit:
+        run_id = f"{wf_name}#{n}"
+        # a root of the run's trace: the run span itself opens only when
+        # the run materialises, after this span's work
+        with self.tracer.span("submit", cat="sched", track="submit",
+                              trace_id=run_id, parent=(run_id, 0)):
+            pwf = workflow if isinstance(workflow, PartitionedWorkflow) \
+                else partition(workflow)
+            wf = pwf.workflow
+            ns = f"run{n}" if namespace is None else namespace
+            mdss = self.mdss if ns == "" else self.mdss.namespaced(
+                ns, shared=self.shared_namespace)
+            if residency_budget and not ns:
+                raise ValueError(
+                    "residency_budget needs a namespaced run (an "
+                    "un-namespaced submission shares the base store)")
+            declared = sum(residency_budget.values()) \
+                if residency_budget else 0
+            deadline_perf = None if deadline_s is None \
+                else time.perf_counter() + deadline_s
+            limit = self.admission_headroom * self.mdss.capacity_bytes \
+                if declared and self.mdss.capacity_bytes else None
+            with self._runs_lock:
+                if self.max_active_runs is not None \
+                        and self._live >= self.max_active_runs:
                     if not park:
                         raise AdmissionRefused(
-                            f"declared residency budget {declared} does not "
-                            f"fit remaining capacity ({committed} of "
-                            f"{limit:.0f} already committed by residency + "
-                            "admitted budgets)")
-                    park_reason = "budget"
-            if park_reason is None:
-                if limit is not None:
-                    self._reserved[run_id] = (ns, declared)
-                self._live += 1
-        if park_reason is not None:
-            return self._park(
-                pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
-                declared, policy, fetch, resume, weight, priority,
-                speculate_after, prefetch, checkpointer, events, on_done,
-                validate, park_reason, deadline_s, deadline_perf, slo_ms)
-        try:
-            return self._submit_admitted(
-                pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
-                policy, fetch, resume, weight, priority, speculate_after,
-                prefetch, checkpointer, events, on_done, validate,
-                slo_ms=slo_ms, deadline_perf=deadline_perf)
-        except BaseException:
-            # anything that fails between admission and the driver taking
-            # ownership must release the reservation — a leak here would
-            # shrink admission capacity forever. The run-slot count
-            # releases symmetrically (same lock, same path) so a rejected
-            # submission can never wedge the front door shut.
-            with self._runs_lock:
-                self._reserved.pop(run_id, None)
-                self._live -= 1
-            raise
+                            f"{self._live} of {self.max_active_runs} run "
+                            "slots busy: submission refused")
+                    park_reason = park_reason or "run_slots"
+                if limit is not None and park_reason is None:
+                    # check + reserve atomically: two concurrent submits
+                    # that each fit alone but not together must not both
+                    # pass. An admitted run's unfilled declared budget is
+                    # capacity it may still legitimately consume.
+                    reserved = sum(
+                        max(0, decl
+                            - self.mdss.namespace_resident_bytes(rns))
+                        for rns, decl in self._reserved.values())
+                    committed = self.mdss.resident_bytes() + reserved
+                    if committed + declared > limit:
+                        if not park:
+                            raise AdmissionRefused(
+                                f"declared residency budget {declared} does "
+                                f"not fit remaining capacity ({committed} of "
+                                f"{limit:.0f} already committed by residency "
+                                "+ admitted budgets)")
+                        park_reason = "budget"
+                if park_reason is None:
+                    if limit is not None:
+                        self._reserved[run_id] = (ns, declared)
+                    self._live += 1
+            if park_reason is not None:
+                return self._park(
+                    pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
+                    declared, policy, fetch, resume, weight, priority,
+                    speculate_after, prefetch, checkpointer, events, on_done,
+                    validate, park_reason, deadline_s, deadline_perf, slo_ms)
+            try:
+                return self._submit_admitted(
+                    pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
+                    policy, fetch, resume, weight, priority, speculate_after,
+                    prefetch, checkpointer, events, on_done, validate,
+                    slo_ms=slo_ms, deadline_perf=deadline_perf)
+            except BaseException:
+                # anything that fails between admission and the driver
+                # taking ownership must release the reservation — a leak
+                # here would shrink admission capacity forever. The run-slot
+                # count releases symmetrically (same lock, same path) so a
+                # rejected submission can never wedge the front door shut.
+                with self._runs_lock:
+                    self._reserved.pop(run_id, None)
+                    self._live -= 1
+                raise
 
     def _park(self, pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
               declared, policy, fetch, resume, weight, priority,
@@ -756,10 +788,11 @@ class EmeraldRuntime:
                      prefetch, checkpointer, handle, sink, slo_ms,
                      deadline_perf) -> "_Run":
         completed: set = set()
-        for uri, val in (init_vars or {}).items():
-            if uri not in wf.variables:
-                wf.var(uri)
-            mdss.put(uri, val, tier="local")
+        with self.tracer.phase("materialize"):
+            for uri, val in (init_vars or {}).items():
+                if uri not in wf.variables:
+                    wf.var(uri)
+                mdss.put(uri, val, tier="local")
         if checkpointer is None and self.checkpoint_dir:
             checkpointer = RunCheckpointer(
                 mdss, wf, self.checkpoint_dir,
@@ -844,10 +877,11 @@ class EmeraldRuntime:
             provided = set(init_vars or ())
             provided |= {u for u in wf.variables
                          if u not in provided and mdss.version(u)}
-        findings = verify(wf, provided=provided,
-                          residency_budget=residency_budget,
-                          tiers=self.manager.tiers,
-                          capacity_bytes=self.mdss.capacity_bytes)
+        with self.tracer.phase("verify"):
+            findings = verify(wf, provided=provided,
+                              residency_budget=residency_budget,
+                              tiers=self.manager.tiers,
+                              capacity_bytes=self.mdss.capacity_bytes)
         errors = [f for f in findings if f.severity == "error"]
         if errors:
             if validate == "error":
@@ -1249,10 +1283,13 @@ class EmeraldRuntime:
 
     # ----------------------------------------------------------- driver loop
     def _drive(self):
+        _name_os_thread("emerald-driver")
         while True:
             msg = self._inbox.get()
             try:
-                if self._drive_one(msg):
+                with self.tracer.phase("drive", msg=msg[0]):
+                    stop = self._drive_one(msg)
+                if stop:
                     return
             except BaseException as e:
                 # a driver-side fault (not a step failure — those ride the
@@ -1477,11 +1514,6 @@ class EmeraldRuntime:
                     wall_now() - t0, span_id=ctx[1],
                     parent_id=run.root_ctx[1], cat="sched", track="driver",
                     shards=st.fanout_shards)
-        if run.root_ctx is not None:
-            self.tracer.add_span(run.run_id, "complete", wall_now(), 0.0,
-                                 parent_id=run.root_ctx[1], cat="sched",
-                                 track="driver", step=name,
-                                 offloaded=offloaded)
         # outputs cached BEFORE successors dispatch (see RunCheckpointer)
         if run.checkpointer is not None:
             run.checkpointer._cache_outputs(run.steps[name])
@@ -1571,11 +1603,14 @@ class EmeraldRuntime:
 
         def reintegrate():
             try:
-                uris = run.fetch if run.fetch is not None else [
-                    u for u in run.wf.variables if run.mdss.version(u)]
-                run.handle._finish(result={
-                    uri: run.mdss.get(uri, "local") for uri in uris
-                    if run.mdss.version(uri)})
+                with self.tracer.span("reintegrate", cat="data",
+                                      track="finalize", trace_id=run.run_id,
+                                      parent=run.root_ctx):
+                    uris = run.fetch if run.fetch is not None else [
+                        u for u in run.wf.variables if run.mdss.version(u)]
+                    result = {uri: run.mdss.get(uri, "local") for uri in uris
+                              if run.mdss.version(uri)}
+                run.handle._finish(result=result)
             except BaseException as e:
                 run.handle._finish(error=e)
 

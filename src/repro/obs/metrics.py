@@ -218,7 +218,6 @@ METRIC_CATALOG: Dict[str, str] = {
     "emcheck.states_deduped": "Explorer prefixes cut by visited-state dedup.",
     "emcheck.por_pruned": "Branches collapsed by partial-order reduction.",
     "emcheck.hazards_found": "Findings raised across explored schedules.",
-    "emcheck.replays": "Reproducer schedules replayed.",
     "fanout.scatters": "Fan-out scatter steps completed.",
     "fanout.shards_dispatched": "Fan-out shard steps granted a lane.",
     "fanout.shards_completed": "Fan-out shard steps completed.",
@@ -241,6 +240,7 @@ METRIC_CATALOG: Dict[str, str] = {
     "mdss.evictions": "Replicas evicted by residency budgets.",
     "mdss.eviction_bytes": "Bytes reclaimed by eviction.",
     "mdss.dedup_bytes_elided": "Bytes elided by content-chunk dedup.",
+    "mdss.bytes_hashed": "Bytes copied to the host and hashed into manifests.",
     "mdss.entries": "Distinct URIs tracked by the store.",
     "mdss.chunk_index_bytes": "Bytes held by the chunk dedup index.",
     "memo.entries": "Cross-run memo table entries.",

@@ -1,7 +1,7 @@
 """Structured tracing for the Emerald runtime (stdlib-only on purpose).
 
 A :class:`Span` is one timed phase of a run — submit, dispatch, place,
-ship, exec, install, complete — identified by ``(trace_id, span_id)``
+ship, exec, install, reintegrate — identified by ``(trace_id, span_id)``
 and parented to the span that was *current on the emitting thread* when
 it opened (or to an explicit parent). The runtime assigns one trace per
 run (``trace_id == run_id``), so a multi-tenant process interleaves N
@@ -29,11 +29,23 @@ lane/worker/tenant, ``X`` (complete) events carrying
 even when time-nesting is ambiguous, and ``M`` metadata events naming
 every process and track.
 
-Overhead: a disabled tracer's ``span()`` returns a shared no-op context
-manager — one attribute load and one ``if`` on the hot path. An enabled
-tracer appends finished spans to a bounded ring (oldest spans drop
-first; ``dropped`` counts them), so a long-lived service never grows an
-unbounded trace log.
+Two sinks, one tracer: besides the ring, an enabled tracer with an
+``annotation`` factory (a profiler's host annotation, e.g.
+``jax.profiler.TraceAnnotation``; the runtime installs it, this module
+imports no profiler) opens ``emerald:<name>`` around every span it opens
+with :meth:`Tracer.span`, carrying the span's ``step`` attribute, so the
+runtime's spans sit on the profiler's own clock beside device work.
+:meth:`Tracer.phase` opens such an annotation only, with nothing kept in
+the ring: for phases inside a layer that matter only under a profiler and
+would overflow the ring if recorded per step. Spans recorded after the
+fact through :meth:`Tracer.add_span` (the run root, a fan-out umbrella,
+worker-reported phases) are ring-only: they were not open on any thread.
+
+Overhead: a disabled tracer's ``span()`` and ``phase()`` return a shared
+no-op context manager — one attribute load and one ``if`` on the hot
+path — and open no annotation. An enabled tracer appends finished spans
+to a bounded ring (oldest spans drop first; ``dropped`` counts them), so
+a long-lived service never grows an unbounded trace log.
 """
 from __future__ import annotations
 
@@ -53,6 +65,9 @@ _EPOCH_WALL = time.time()
 _EPOCH_PERF = time.perf_counter()
 
 SpanCtx = Tuple[str, int]          # (trace_id, span_id)
+
+# name prefix of every profiler annotation the tracer opens
+ANNOTATION_PREFIX = "emerald:"
 
 
 def wall_of(perf_t: float) -> float:
@@ -94,14 +109,16 @@ _NOOP = _NoopSpan()
 
 
 class _ActiveSpan:
-    """An open span: records on exit, exposes ``ctx`` for propagation."""
-    __slots__ = ("tracer", "span", "_t0_perf", "_stack")
+    """An open span: records on exit, exposes ``ctx`` for propagation,
+    and holds the span's profiler annotation while it is open."""
+    __slots__ = ("tracer", "span", "_t0_perf", "_stack", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span, stack: list):
         self.tracer = tracer
         self.span = span
         self._stack = stack
         self._t0_perf = 0.0
+        self._ann = None
 
     @property
     def ctx(self) -> SpanCtx:
@@ -111,6 +128,13 @@ class _ActiveSpan:
         self.span.attrs.update(attrs)
 
     def __enter__(self):
+        factory = self.tracer.annotation
+        if factory is not None:
+            sp = self.span
+            step = sp.attrs.get("step")
+            self._ann = factory(ANNOTATION_PREFIX + sp.name) if step is None \
+                else factory(ANNOTATION_PREFIX + sp.name, step=step)
+            self._ann.__enter__()
         self._t0_perf = time.perf_counter()
         self.span.t0_wall = wall_of(self._t0_perf)
         self._stack.append(self.ctx)
@@ -118,6 +142,8 @@ class _ActiveSpan:
 
     def __exit__(self, exc_type, exc, tb):
         self.span.dur_s = time.perf_counter() - self._t0_perf
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if exc_type is not None:
             self.span.attrs["error"] = repr(exc)
         stack = self._stack
@@ -161,6 +187,9 @@ class Tracer:
         self._tls = threading.local()
         self.dropped = 0
         self.pid = os.getpid()
+        # profiler host-annotation factory, ``(name, **attrs) -> context
+        # manager``; None keeps spans in the ring alone
+        self.annotation = None
 
     # ------------------------------------------------------------- recording
     def _stack(self) -> list:
@@ -195,6 +224,15 @@ class Tracer:
                   parent[1] if parent is not None else 0,
                   name, cat=cat, track=track, pid=self.pid, attrs=attrs)
         return _ActiveSpan(self, sp, stack)
+
+    def phase(self, name: str, **attrs):
+        """Open a profiler annotation ``emerald:<name>`` carrying
+        ``attrs``, and record nothing in the ring. A no-op when the tracer
+        is disabled or no annotation factory is installed."""
+        factory = self.annotation
+        if not self.enabled or factory is None:
+            return _NOOP
+        return factory(ANNOTATION_PREFIX + name, **attrs)
 
     def attach(self, ctx: Optional[SpanCtx]):
         """Context manager making ``ctx`` this thread's current span."""

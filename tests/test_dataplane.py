@@ -1,6 +1,7 @@
 """Content-addressed data plane: chunked wire format edge cases, digest
 dedup at the socket / fabric / MDSS layers, per-direction bandwidth in
 placement, cross-run step memoization, budget-aware admission."""
+import collections
 import socket
 import threading
 import time
@@ -185,6 +186,42 @@ def test_content_digest_tracks_value_not_uri():
     mdss.put("p/x", v + 1, tier="local")
     assert mdss.content_digest("p/x") != mdss.content_digest("q/y")
     assert content_digest({"a": v}) != content_digest({"b": v})
+
+
+def test_host_copy_before_hashing_keeps_every_digest():
+    """The store copies device arrays to the host as a step of its own
+    (``to_host``) before hashing. Manifests are what hashing the device
+    value gave: the digests below were computed on the same value before
+    that split existed."""
+    import jax.numpy as jnp
+
+    from repro.cloud.wire import to_host
+    value = {"b": jnp.arange(300_000, dtype=jnp.float32).reshape(300, 1000),
+             "a": (np.arange(5, dtype=np.int32), [jnp.float32(2.5)]),
+             "c": (jnp.ones(3, jnp.bfloat16), 7), "d": np.float64(1.5),
+             "e": None}
+    want = manifest_of(value)
+    assert want[0].hex() == "6e62018a64f7fe24f713540ea938ed0a"
+    assert [d.hex() for d, _ in want[1]] == [
+        "a9179a1d3a7953e8b9ebe28512a060b5",
+        "56fab19886f026c3b1dee10afc472648",
+        "e528f4309e1413e6bc35aea5d8db8519",
+        "072e3304b03423a4767d28c5fed09f81",
+        "dc6a48767bd84de83df12675d0f9e490"]
+    host = to_host(value)
+    assert isinstance(host["b"], np.ndarray) and host["e"] is None \
+        and isinstance(host["a"][1][0], np.ndarray) and host["c"][1] == 7
+    assert manifest_of(host) == want
+    mdss = make_mgr().mdss
+    assert mdss._manifest(value) == want
+    assert mdss.bytes_hashed == 300_000 * 4 + 5 * 4 + 4 + 3 * 2 + 8 + 8
+    # a namedtuple keeps its type through the host copy
+    pair = HostPair(x=jnp.ones(4), y="tag")
+    assert type(to_host(pair)) is HostPair
+    assert manifest_of(to_host(pair)) == manifest_of(pair)
+
+
+HostPair = collections.namedtuple("HostPair", "x y")
 
 
 # ------------------------------------------------- asymmetric placement

@@ -283,7 +283,8 @@ def test_chrome_trace_export_validates(tmp_path):
     names = {e["name"] for e in xs}
     # "place" appears only under a locality policy; this run exercises
     # the default should_offload path
-    assert {"run", "dispatch", "exec", "install", "complete"} <= names
+    assert {"run", "dispatch", "exec", "install", "submit",
+            "reintegrate"} <= names
     span_ids = set()
     for e in xs:
         assert isinstance(e["ts"], (int, float))
@@ -311,6 +312,152 @@ def test_chrome_trace_sanitises_non_json_attrs():
     x = [e for e in doc["traceEvents"] if e["ph"] == "X"][0]
     json.dumps(doc)                         # must be serialisable
     assert isinstance(x["args"]["obj"], str) and x["args"]["ok"] == 1
+
+
+# ---------------------------------------------------------- profiler bridge
+class _Closes:
+    def __init__(self, rec):
+        self.rec = rec
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.rec[2] = True
+        return False
+
+
+class Annotations:
+    """Stands in for a profiler's host-annotation factory and keeps what
+    was opened, with its attributes and whether it was closed."""
+
+    def __init__(self):
+        self.opened = []
+        self._lock = threading.Lock()
+
+    def __call__(self, name, **attrs):
+        rec = [name, attrs, False]
+        with self._lock:
+            self.opened.append(rec)
+        return _Closes(rec)
+
+    def names(self):
+        return {name for name, _, _ in self.opened}
+
+
+def tiny_at():
+    """The paper's 4-step AT workflow at a mesh the CPU runs in a blink,
+    with its initial variables."""
+    import jax.numpy as jnp
+
+    from repro.apps.adjoint_tomography import (ATConfig, build_workflow,
+                                               starting_model)
+    cfg = ATConfig(nx=12, ny=6, nz=6, nt=8, n_receivers=3)
+    return build_workflow(cfg), {
+        "model": starting_model(cfg),
+        "obs": jnp.zeros((cfg.nt, cfg.n_receivers), jnp.float32)}
+
+
+def test_tracer_bridges_spans_and_phases_to_annotations():
+    ann = Annotations()
+    tr = Tracer()
+    tr.annotation = ann
+    with tr.span("exec", step="forward"):
+        with tr.phase("hash", bytes=64):
+            pass
+    with tr.span("submit"):
+        pass
+    tr.add_span("t", "run", wall_now(), 0.0, step="forward")
+    assert ann.opened == [["emerald:exec", {"step": "forward"}, True],
+                          ["emerald:hash", {"bytes": 64}, True],
+                          ["emerald:submit", {}, True]]
+    # the ring holds the spans, not the phase; add_span is ring-only
+    assert [s.name for s in tr.spans()] == ["exec", "submit", "run"]
+
+
+def test_one_at_iteration_records_nineteen_spans():
+    """run + dispatch/ship/exec/install of each of the 4 steps + submit +
+    reintegrate, all in the run's own trace."""
+    wf, init = tiny_at()
+    with EmeraldRuntime(emerald(), max_workers=2) as rt:
+        h = rt.submit(wf, init, fetch=("chi",))
+        h.result(120)
+        spans = rt.tracer.spans()
+    assert sorted(s.name for s in spans) == sorted(
+        ["run", "submit", "reintegrate"]
+        + ["dispatch", "ship", "exec", "install"] * 4)
+    assert {s.trace_id for s in spans} == {h.trace_id}
+    root = next(s for s in spans if s.name == "run")
+    assert next(s for s in spans if s.name == "reintegrate").parent_id \
+        == root.span_id
+    assert {s.attrs["step"] for s in spans if s.name == "exec"} \
+        == {"forward", "misfit", "kernel", "update"}
+
+
+@pytest.mark.parametrize("telemetry", [True, False])
+def test_annotations_open_exactly_when_telemetry_is_on(telemetry):
+    ann = Annotations()
+    tracer = Tracer(enabled=telemetry)
+    tracer.annotation = ann
+    wf, init = tiny_at()
+    with EmeraldRuntime(emerald(), max_workers=2, telemetry=telemetry,
+                        tracer=tracer) as rt:
+        assert rt.mdss.tracer is tracer
+        rt.submit(wf, init, fetch=("chi",)).result(120)
+    if not telemetry:
+        assert ann.opened == [] and tracer.spans() == []
+        return
+    assert all(closed for _, _, closed in ann.opened)
+    assert {"emerald:" + n for n in (
+        "submit", "verify", "materialize", "drive", "dispatch", "ship",
+        "exec", "install", "d2h", "hash", "reintegrate")} <= ann.names()
+    assert {a["msg"] for n, a, _ in ann.opened if n == "emerald:drive"} \
+        >= {"submit", "done"}
+
+
+def stat(ev, key):
+    return next(v for k, v in ev.stats if k == key)
+
+
+def test_cpu_profiler_trace_holds_the_runtime_spans(tmp_path):
+    """One run under the real profiler (CPU): the runtime's spans and
+    phases are host events on the profiler's clock, and the ``bytes`` of
+    its ``hash`` phases add up to the store's own count."""
+    import jax
+    from jax.profiler import ProfileData
+    wf, init = tiny_at()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with EmeraldRuntime(emerald(), max_workers=2) as rt:
+            rt.submit(wf, init, fetch=("chi",), prefetch=False).result(120)
+            hashed = rt.metrics.snapshot()["mdss.bytes_hashed"]
+    finally:
+        jax.profiler.stop_trace()
+    path = next(os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+                for f in fs if f.endswith(".xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("emerald:")]
+    names = {ev.name for ev in events}
+    assert {"emerald:" + n for n in (
+        "submit", "verify", "materialize", "drive", "d2h", "hash", "exec",
+        "install", "reintegrate")} <= names
+    assert hashed > 0
+    assert sum(stat(ev, "bytes") for ev in events
+               if ev.name == "emerald:hash") == hashed
+    assert {stat(ev, "step") for ev in events
+            if ev.name == "emerald:exec"} \
+        == {"forward", "misfit", "kernel", "update"}
+
+
+def test_at_step_programs_bear_their_step_names():
+    import jax
+    wf, init = tiny_at()
+    for name in ("forward", "misfit", "kernel", "update"):
+        assert wf.steps[name].fn.__name__ == name
+    lowered = jax.jit(wf.steps["forward"].fn).lower(init["model"])
+    assert "jit_forward" in lowered.as_text()
 
 
 # ----------------------------------------------------------- event schema

@@ -350,7 +350,7 @@ def test_owned_runtime_reaped_without_result_call():
     assert h.wait(10)
 
     def driver_alive():
-        return any(t.name == "emerald-reapme-driver"
+        return any(t.name == "emerald-driver:emerald-reapme"
                    for t in threading.enumerate())
 
     deadline = time.monotonic() + 5

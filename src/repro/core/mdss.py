@@ -28,18 +28,24 @@ URI-keyed, versioned, multi-tier data store:
     outputs while warm cross-run data (params, observations) is stored —
     and stays cloud-resident — exactly once. ``drop_namespace`` is run
     teardown: it frees every replica the run published,
-  * **content addressing** (chunk dedup): every replica install registers
-    its value's chunk digests (``wire.manifest_of``) in a per-tier chunk
-    index carrying the same incremental residency accounting as the
-    byte counters; ``staleness``/``stale_bytes`` then charge only chunks
-    NOT already resident on the destination tier — a second tenant
-    staging content-identical inputs (same params under another
-    namespace, a re-upload after eviction) owes **zero** transfer bytes,
-    and the locality scorer (``CostModel.placement_cost``) sees exactly
-    that. A transport exposing ``transfer_ex`` (the fabric's
+  * **content addressing** (chunk dedup): a replica install whose
+    manifest (``wire.manifest_of``) is known registers its chunk digests
+    in a per-tier chunk index carrying the same incremental residency
+    accounting as the byte counters; ``staleness``/``stale_bytes`` then
+    charge only chunks NOT already resident on the destination tier — a
+    second tenant staging content-identical inputs (same params under
+    another namespace, a re-upload after eviction) owes **zero** transfer
+    bytes, and the locality scorer (``CostModel.placement_cost``) sees
+    exactly that. A transport exposing ``transfer_ex`` (the fabric's
     RPCTransport) ships metadata only for fully-resident values;
     ``content_digest(uri)`` is the whole-value identity the runtime's
-    cross-run step memoization keys on,
+    cross-run step memoization keys on. When a manifest is computed: a
+    value whose leaves are all on the host is hashed at install; a value
+    resident on the device (any ``jax.Array`` leaf, which hashing would
+    first copy to the host) is hashed only when a digest is demanded —
+    by ``content_digest`` or by a ship through a chunk-aware transport —
+    at most once per version. Until then it is charged its full bytes
+    when staged on another tier and adds no rows to any chunk index,
   * **residency budgets** (per namespace, per tier): resident bytes are
     accounted incrementally on every copy install/replace/delete, and
     ``set_namespace_budget(ns, tier, max_bytes)`` bounds a namespace's
@@ -114,6 +120,12 @@ def nbytes_of(value) -> int:
     return total
 
 
+def on_device(value) -> bool:
+    """True when any leaf of ``value`` is a ``jax.Array``: hashing it would
+    first copy it from the device to the host."""
+    return any(isinstance(leaf, jax.Array) for leaf in jax.tree.leaves(value))
+
+
 class Transport:
     """Moves a value between tiers; override for a real RPC fabric."""
 
@@ -141,11 +153,12 @@ class MDSS:
         self.tiers = tiers
         self.transport = transport or Transport(tiers)
         self.cost_model = cost_model
-        # content-addressed residency: replica installs register chunk
-        # digests per tier, and transfer obligations charge only chunks
-        # not already resident at the destination (values are treated as
-        # immutable once stored — mutating a stored array in place would
-        # stale its cached manifest)
+        # content-addressed residency: replica installs with a known
+        # manifest register chunk digests per tier, and transfer
+        # obligations charge only chunks not already resident at the
+        # destination (values are treated as immutable once stored —
+        # mutating a stored array in place would stale its cached
+        # manifest)
         self.chunk_dedup = chunk_dedup
         # store-wide resident-byte ceiling; the runtime's admission
         # control refuses new submissions when residency nears it
@@ -203,11 +216,18 @@ class MDSS:
         # the last replica referencing them leaves the tier (eviction,
         # drop_namespace, overwrite)
         self._tier_chunks: Dict[str, Dict[bytes, list]] = {}
+        # (uri, tier) -> the chunk list that copy added to its tier's
+        # index. Only copies installed with a known manifest are here; a
+        # release removes exactly these rows, so a manifest computed
+        # after install (content_digest) never unbalances the index
+        self._copy_chunks: Dict[Tuple[str, str], list] = {}
         self._manifest_cache: "OrderedDict[Tuple[str, int], tuple]" = \
             OrderedDict()
         self.manifest_cache_cap = 4096
         self.dedup_bytes_elided: int = 0   # transfer bytes chunk-dedup saved
         self.bytes_hashed: int = 0         # bytes put through _manifest
+        self.manifests_deferred: int = 0   # device-value installs not hashed
+        self.manifests_on_demand: int = 0  # deferred manifests computed later
         # disabled by default; an owning runtime swaps in its live tracer
         # so the d2h and hash phases of every manifest reach the profiler
         self.tracer = Tracer(enabled=False)
@@ -222,12 +242,13 @@ class MDSS:
         store lock). A stale writer — e.g. a speculation loser finishing
         after the winner already published — gets ``None`` back and the
         entry is untouched. ``_manifest`` lets batch callers pre-hash the
-        value's chunk manifest outside the store lock.
+        value's chunk manifest outside the store lock. A value resident on
+        the device is not hashed here (module docstring).
         """
-        if _manifest is None and self.chunk_dedup:
+        if _manifest is None:
             # hash before taking the lock (re-entrant callers that
             # already hold it pay under the lock, same as before)
-            _manifest = self._manifest(value)
+            _manifest = self._install_manifest(value)
         with self._lock:
             e = self._entries.setdefault(uri, _Entry())
             if expect_version is not None and e.version != expect_version:
@@ -237,14 +258,22 @@ class MDSS:
             e.writer = tier
             if _manifest is not None:
                 self._cache_manifest((uri, e.version), _manifest)
+            elif self.chunk_dedup:
+                self.manifests_deferred += 1
             self._set_copy(uri, e, tier, e.version, value)
             return e.version
 
+    def _install_manifest(self, value) -> Optional[tuple]:
+        """The manifest an install computes up front: a host value's, or
+        None when dedup is off or the value is resident on the device."""
+        if not self.chunk_dedup or on_device(value):
+            return None
+        return self._manifest(value)
+
     def _premanifests(self, values: Dict[str, Any]) -> Dict[str, tuple]:
         """Hash a batch's manifests with NO lock held (for put_many)."""
-        if not self.chunk_dedup:
-            return {}
-        return {uri: self._manifest(val) for uri, val in values.items()}
+        return {uri: self._install_manifest(val)
+                for uri, val in values.items()}
 
     def put_many(self, values: Dict[str, Any], tier: str = "local",
                  expect_versions: Optional[Dict[str, int]] = None):
@@ -326,10 +355,12 @@ class MDSS:
         destination tier does not already hold under ANY entry — staging
         content-identical data (another tenant's copy of the same
         params, a re-upload after eviction) owes nothing, which is
-        exactly what ``CostModel.placement_cost`` should charge.
+        exactly what ``CostModel.placement_cost`` should charge. A value
+        resident on the device is charged its full bytes unless the
+        transport is chunk-aware (no manifest is computed for it).
         """
         uris = list(uris)
-        self._warm_manifests(uris)          # hash misses outside the lock
+        self._warm_manifests(uris, tier)    # hash misses outside the lock
         out: List[Tuple[str, str, int]] = []
         with self._lock:
             for uri in uris:
@@ -340,7 +371,7 @@ class MDSS:
                 if src is None:
                     continue
                 version, value = e.copies[src]
-                if self.chunk_dedup:
+                if self._dedup_applies(value):
                     chunks = self._manifest_for(uri, version, value)[1]
                     n = self._missing_chunk_bytes(tier, chunks)
                 else:
@@ -372,7 +403,7 @@ class MDSS:
     def _ensure_one(self, uri: str, tier: str) -> int:
         moved = 0
         expired_waits = 0
-        self._warm_manifests([uri])         # hash misses outside the lock
+        self._warm_manifests([uri], tier)   # hash misses outside the lock
         while True:
             peer = None
             with self._lock:
@@ -389,7 +420,7 @@ class MDSS:
                         raise KeyError(f"{uri}: no replica anywhere")
                     snap_version = e.version
                     value = e.copies[src][1]
-                    if self.chunk_dedup:
+                    if self._dedup_applies(value):
                         chunks = self._manifest_for(
                             uri, snap_version, value)[1]
                         missing = self._missing_chunk_bytes(tier, chunks)
@@ -418,7 +449,8 @@ class MDSS:
                 # transport (transfer_ex) ships only non-resident chunks
                 # — a fully-resident value is a metadata-only round trip
                 # — and reports the bytes it actually owed; the default
-                # transport is charged the same dedup-aware obligation.
+                # transport is charged the same dedup-aware obligation
+                # for a host value, the full bytes for a device value.
                 transfer_ex = getattr(self.transport, "transfer_ex", None)
                 if transfer_ex is not None:
                     shipped, n = transfer_ex(value, src, tier,
@@ -548,8 +580,17 @@ class MDSS:
         if got is not None:
             self._manifest_cache.move_to_end(key)
             return got
-        mani = self._manifest(value)
+        mani = self._stored_manifest(value)
         self._cache_manifest(key, mani)
+        return mani
+
+    def _stored_manifest(self, value):
+        """``_manifest`` of a value already in the store, counting a
+        device value's deferred manifest as computed on demand."""
+        mani = self._manifest(value)
+        if on_device(value):
+            with self._lock:
+                self.manifests_on_demand += 1
         return mani
 
     def _cache_manifest(self, key, mani):
@@ -557,13 +598,26 @@ class MDSS:
         while len(self._manifest_cache) > self.manifest_cache_cap:
             self._manifest_cache.popitem(last=False)
 
-    def _warm_manifests(self, uris):
+    def _dedup_applies(self, value) -> bool:
+        """Whether staging ``value`` on another tier is priced by its
+        chunks: always for a host value, and for a device value only
+        through a chunk-aware transport, which ships it by its manifest
+        (the copy to the host happens there anyway)."""
+        if not self.chunk_dedup:
+            return False
+        return getattr(self.transport, "transfer_ex", None) is not None \
+            or not on_device(value)
+
+    def _warm_manifests(self, uris, tier: Optional[str] = None):
         """Hash any manifest-cache misses for ``uris``' freshest replicas
         with NO lock held, then seed the cache. The read paths
         (staleness, content_digest, ensure) call this first so their
         under-lock work is dict lookups, not SHA-256 of multi-MB values
         — a racing version bump can still miss and hash under the lock,
-        but that is the rare case, not the steady state."""
+        but that is the rare case, not the steady state. With ``tier``
+        (staging there), URIs already current on it and values whose
+        staging is not priced by chunks are skipped: nothing demands
+        their digests."""
         if not self.chunk_dedup:
             return
         with self._lock:
@@ -572,39 +626,55 @@ class MDSS:
                 e = self._entries.get(uri)
                 if e is None:
                     continue
+                if tier is not None and self.has_latest(uri, tier):
+                    continue
                 src = self._freshest_tier(e)
                 if src is None:
                     continue
                 version, value = e.copies[src]
-                if (uri, version) not in self._manifest_cache:
+                if (uri, version) in self._manifest_cache:
+                    continue
+                if tier is None or self._dedup_applies(value):
                     todo.append((uri, version, value))
         if not todo:
             return
-        hashed = [(u, v, self._manifest(val)) for u, v, val in todo]
+        hashed = [(u, v, self._stored_manifest(val)) for u, v, val in todo]
         with self._lock:
             for u, v, mani in hashed:
                 if (u, v) not in self._manifest_cache:
                     self._cache_manifest((u, v), mani)
 
-    def _chunks_retain(self, tier: str, uri: str, version: int, value):
+    def _index_copy(self, uri: str, tier: str, version: int, value):
+        """Lock held: add a new copy's chunks to ``tier``'s index when its
+        manifest is known — cached, or computed now for a host value. A
+        device value with no manifest yet adds no rows."""
+        mani = self._manifest_cache.get((uri, version))
+        if mani is None:
+            if on_device(value):
+                return
+            mani = self._manifest_for(uri, version, value)
+        chunks = mani[1]
+        self._copy_chunks[(uri, tier)] = chunks
         idx = self._tier_chunks.setdefault(tier, {})
-        for d, ln in self._manifest_for(uri, version, value)[1]:
+        for d, ln in chunks:
             ent = idx.get(d)
             if ent is None:
                 idx[d] = [1, ln]
             else:
                 ent[0] += 1
 
-    def _chunks_release(self, tier: str, uri: str, version: int, value):
-        idx = self._tier_chunks.get(tier)
-        if idx is None:
+    def _unindex_copy(self, uri: str, tier: str):
+        """Lock held: remove exactly the rows ``_index_copy`` added for
+        ``tier``'s copy of ``uri`` (none if it added none)."""
+        chunks = self._copy_chunks.pop((uri, tier), None)
+        if chunks is None:
             return
-        for d, _ in self._manifest_for(uri, version, value)[1]:
-            ent = idx.get(d)
-            if ent is not None:
-                ent[0] -= 1
-                if ent[0] <= 0:
-                    del idx[d]
+        idx = self._tier_chunks[tier]
+        for d, _ in chunks:
+            ent = idx[d]
+            ent[0] -= 1
+            if ent[0] <= 0:
+                del idx[d]
 
     def _missing_chunk_bytes(self, tier: str, chunks) -> int:
         """Bytes of ``chunks`` not resident on ``tier`` — lock held."""
@@ -641,13 +711,12 @@ class MDSS:
         if old is not None:
             self._ns_tier_bytes[key] = \
                 self._ns_tier_bytes.get(key, 0) - nbytes_of(old[1])
-            if self.chunk_dedup:
-                self._chunks_release(tier, uri, old[0], old[1])
+            self._unindex_copy(uri, tier)
         e.copies[tier] = (version, value)
         self._ns_tier_bytes[key] = \
             self._ns_tier_bytes.get(key, 0) + nbytes_of(value)
         if self.chunk_dedup:
-            self._chunks_retain(tier, uri, version, value)
+            self._index_copy(uri, tier, version, value)
         self.installs_total += 1
         self.install_events.append(
             (uri, tier, version, self._ns_epoch.get(key[0], 0),
@@ -663,8 +732,7 @@ class MDSS:
         old = e.copies.pop(tier, None)
         if old is None:
             return 0
-        if self.chunk_dedup:
-            self._chunks_release(tier, uri, old[0], old[1])
+        self._unindex_copy(uri, tier)
         n = nbytes_of(old[1])
         key = (namespace_of(uri), tier)
         left = self._ns_tier_bytes.get(key, 0) - n
@@ -857,9 +925,8 @@ class MDSS:
                 for t in list(e.copies):
                     freed += self._del_copy(u, e, t)
                 del self._entries[u]
-            # purge the dropped URIs' cached manifests (AFTER the
-            # deletions — _del_copy's chunk release re-warms them): a
-            # reused namespace restarts versions at 1, and a stale
+            # purge the dropped URIs' cached manifests: a reused
+            # namespace restarts versions at 1, and a stale
             # (uri, version) hit would hand the OLD content's digest to
             # new data — wrong memo keys, wrong residency pricing
             dead = set(doomed)
@@ -890,6 +957,10 @@ class MDSS:
         registry.gauge("mdss.dedup_bytes_elided",
                        lambda: self.dedup_bytes_elided)
         registry.gauge("mdss.bytes_hashed", lambda: self.bytes_hashed)
+        registry.gauge("mdss.manifests_deferred",
+                       lambda: self.manifests_deferred)
+        registry.gauge("mdss.manifests_on_demand",
+                       lambda: self.manifests_on_demand)
         registry.gauge("mdss.entries", lambda: len(self._entries))
         registry.gauge("mdss.chunk_index_bytes", self._chunk_index_bytes)
 
@@ -1006,7 +1077,7 @@ class NamespacedMDSS:
             if self.version(uri) != expect_version:
                 self.base.fenced_puts += 1
                 return None
-        mani = self.base._manifest(value) if self.base.chunk_dedup else None
+        mani = self.base._install_manifest(value)
         with self.base._lock:
             if self.version(uri) != expect_version:
                 self.base.fenced_puts += 1

@@ -241,6 +241,10 @@ METRIC_CATALOG: Dict[str, str] = {
     "mdss.eviction_bytes": "Bytes reclaimed by eviction.",
     "mdss.dedup_bytes_elided": "Bytes elided by content-chunk dedup.",
     "mdss.bytes_hashed": "Bytes copied to the host and hashed into manifests.",
+    "mdss.manifests_deferred": "Device-value installs whose manifest was not "
+                               "computed at install.",
+    "mdss.manifests_on_demand": "Deferred manifests computed later, for a "
+                                "digest or a chunk-aware ship.",
     "mdss.entries": "Distinct URIs tracked by the store.",
     "mdss.chunk_index_bytes": "Bytes held by the chunk dedup index.",
     "memo.entries": "Cross-run memo table entries.",

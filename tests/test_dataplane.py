@@ -2,6 +2,7 @@
 dedup at the socket / fabric / MDSS layers, per-direction bandwidth in
 placement, cross-run step memoization, budget-aware admission."""
 import collections
+import contextlib
 import socket
 import threading
 import time
@@ -224,6 +225,157 @@ def test_host_copy_before_hashing_keeps_every_digest():
 HostPair = collections.namedtuple("HostPair", "x y")
 
 
+# ------------------------------------ deferred manifests of device values
+class PhaseLog:
+    """Annotation factory that records the names a tracer opens."""
+
+    def __init__(self):
+        self.names = []
+
+    def __call__(self, name, **attrs):
+        self.names.append(name)
+        return contextlib.nullcontext()
+
+
+def traced_mdss():
+    from repro.obs.tracing import Tracer
+    mdss = make_mgr().mdss
+    mdss.tracer = Tracer()
+    mdss.tracer.annotation = log = PhaseLog()
+    return mdss, log
+
+
+def test_device_value_install_is_not_hashed():
+    import jax.numpy as jnp
+    mdss, log = traced_mdss()
+    mdss.put("a/v", jnp.arange(4096, dtype=jnp.float32), tier="local")
+    mdss.put_many({"a/w": jnp.ones(8), "a/h": np.ones(8)}, tier="cloud")
+    assert mdss.manifests_deferred == 2 and mdss.manifests_on_demand == 0
+    assert mdss.bytes_hashed == np.ones(8).nbytes      # the host value only
+    assert log.names.count("emerald:hash") == 1
+    assert log.names.count("emerald:d2h") == 1
+    assert mdss._manifest_cache.keys() == {("a/h", 1)}
+
+
+def test_content_digest_of_a_device_value_is_computed_once():
+    import jax.numpy as jnp
+
+    from repro.cloud.wire import to_host
+    mdss, log = traced_mdss()
+    v = {"m": jnp.arange(4096, dtype=jnp.float32), "s": jnp.float32(2)}
+    mdss.put("a/v", v, tier="local")
+    assert mdss.bytes_hashed == 0 and log.names == []
+    assert mdss.content_digest("a/v") == manifest_of(to_host(v))[0]
+    assert mdss.manifests_on_demand == 1
+    hashed = mdss.bytes_hashed
+    assert hashed == 4096 * 4 + 4
+    assert mdss.content_digest("a/v") == manifest_of(to_host(v))[0]
+    assert mdss.bytes_hashed == hashed and mdss.manifests_on_demand == 1
+    assert log.names == ["emerald:d2h", "emerald:hash"]
+
+
+def test_device_value_staged_elsewhere_owes_its_full_bytes():
+    import jax.numpy as jnp
+    mdss, log = traced_mdss()
+    v = jnp.arange(4096, dtype=jnp.float32)
+    mdss.put("a/v", v, tier="local")
+    mdss.put("b/v", v, tier="cloud")       # same content, already on cloud
+    assert mdss.stale_bytes(["a/v"], "cloud") == v.nbytes
+    assert mdss.stale_bytes(["a/v"], "local") == 0
+    assert mdss.ensure(["a/v"], "cloud") == v.nbytes
+    assert mdss.stale_bytes(["a/v"], "cloud") == 0
+    assert mdss.bytes_hashed == 0 and log.names == []
+    assert mdss._manifest_cache == {}
+    assert mdss.tier_chunk_stats("cloud") == (0, 0)
+
+
+class ChunkAwareTransport:
+    """Stands in for a transport that ships values by their manifests."""
+
+    def __init__(self):
+        self.chunks = []
+
+    def transfer_ex(self, value, src, dst, chunks=None, missing_bytes=None):
+        self.chunks.append(chunks)
+        return value, missing_bytes
+
+
+def test_chunk_aware_ship_computes_a_device_value_manifest():
+    import jax.numpy as jnp
+    mdss = make_mgr().mdss
+    mdss.transport = ChunkAwareTransport()
+    v = jnp.arange(4096, dtype=jnp.float32)
+    mdss.put("a/v", v, tier="local")
+    assert mdss.bytes_hashed == 0
+    assert mdss.stale_bytes(["a/v"], "cloud") == v.nbytes
+    assert mdss.ensure(["a/v"], "cloud") == v.nbytes
+    assert mdss.transport.chunks == [manifest_of(np.asarray(v))[1]]
+    assert mdss.manifests_on_demand == 1 and mdss.bytes_hashed == v.nbytes
+    # the shipped copy was installed with its manifest known: indexed
+    assert mdss.tier_chunk_stats("cloud") == (1, v.nbytes)
+    assert mdss.tier_chunk_stats("local") == (0, 0)
+
+
+def chunk_index_from_copies(mdss, tier):
+    """The tier's chunk index rebuilt from the copies recorded as indexed."""
+    idx = collections.Counter()
+    lengths = {}
+    for (uri, t), chunks in mdss._copy_chunks.items():
+        assert tier != t or tier in mdss._entries[uri].copies
+        if t == tier:
+            for d, ln in chunks:
+                idx[d] += 1
+                lengths[d] = ln
+    return idx, lengths
+
+
+def assert_index_in_lockstep(mdss):
+    for (uri, t) in mdss._copy_chunks:
+        assert t in mdss._entries[uri].copies
+    for tier in mdss.tiers:
+        idx, lengths = chunk_index_from_copies(mdss, tier)
+        live = mdss._tier_chunks.get(tier, {})
+        assert all(ref > 0 for ref, _ in live.values())
+        assert {d: ref for d, (ref, _) in live.items()} == dict(idx)
+        assert mdss.tier_chunk_stats(tier) == (len(idx),
+                                               sum(lengths.values()))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_chunk_index_stays_in_lockstep_with_mixed_values(seed):
+    """Host and device puts, stagings across tiers, digests demanded of
+    deferred values and namespace drops, in a seeded order: the chunk
+    index always equals the one rebuilt from the indexed copies, and is
+    empty once every namespace is gone."""
+    import random
+
+    import jax.numpy as jnp
+    rng = random.Random(seed)
+    mdss = make_mgr().mdss
+    host = [np.full(256, k, np.float32) for k in range(3)]
+    device = [jnp.asarray(h) for h in host]
+    tiers = list(mdss.tiers)
+    uris = [f"{ns}/{leaf}" for ns in ("a", "b") for leaf in ("x", "y")]
+    for _ in range(60):
+        op = rng.choice(("put", "put", "ensure", "digest", "drop"))
+        uri = rng.choice(uris)
+        if op == "put":
+            pool = rng.choice((host, device))
+            mdss.put(uri, pool[rng.randrange(3)], tier=rng.choice(tiers))
+        elif op == "ensure" and uri in mdss._entries:
+            mdss.ensure([uri], rng.choice(tiers))
+        elif op == "digest" and uri in mdss._entries:
+            mdss.content_digest(uri)
+        elif op == "drop":
+            mdss.drop_namespace(uri.split("/")[0])
+        assert_index_in_lockstep(mdss)
+    for ns in ("a", "b"):
+        mdss.drop_namespace(ns)
+    assert_index_in_lockstep(mdss)
+    assert mdss._copy_chunks == {}
+    assert all(mdss.tier_chunk_stats(t) == (0, 0) for t in tiers)
+
+
 # ------------------------------------------------- asymmetric placement
 def test_placement_tracks_asymmetric_link():
     """Force an asymmetric link: a fast up (local->cloud), slow down
@@ -290,9 +442,15 @@ def make_tenant(name):
     return wf
 
 
-def test_memoized_duplicate_submission_executes_once():
+@pytest.mark.parametrize("device", [False, True])
+def test_memoized_duplicate_submission_executes_once(device):
+    """Device inputs carry no manifest from their install: the memo key
+    computes their digests on demand and still shares one execution."""
+    import jax.numpy as jnp
     HEAVY_CALLS.clear()
     P = np.random.rand(1 << 14)
+    if device:
+        P = jnp.asarray(P)
     with EmeraldRuntime(memoize=True) as rt:
         h1 = rt.submit(make_tenant("t1"), {"P": P}, fetch=["out"])
         h2 = rt.submit(make_tenant("t2"), {"P": P}, fetch=["out"])
@@ -303,6 +461,8 @@ def test_memoized_duplicate_submission_executes_once():
              if e.kind in ("local", "offload") and e.step == "heavy"]
     assert sorted(e.info["memo_hit"] for e in execs) == [False, True]
     assert rt.manager.memo_hits == 1
+    # each tenant's P: its digest computed for the memo key, not at install
+    assert rt.mdss.manifests_on_demand == (2 if device else 0)
 
 
 def test_memoization_respects_input_content():

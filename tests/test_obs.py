@@ -396,12 +396,15 @@ def test_one_at_iteration_records_nineteen_spans():
 
 @pytest.mark.parametrize("telemetry", [True, False])
 def test_annotations_open_exactly_when_telemetry_is_on(telemetry):
+    """Under memoization the store computes the digests of the AT
+    workflow's device-resident values, so its ``d2h`` and ``hash`` phases
+    open too."""
     ann = Annotations()
     tracer = Tracer(enabled=telemetry)
     tracer.annotation = ann
     wf, init = tiny_at()
     with EmeraldRuntime(emerald(), max_workers=2, telemetry=telemetry,
-                        tracer=tracer) as rt:
+                        tracer=tracer, memoize=True) as rt:
         assert rt.mdss.tracer is tracer
         rt.submit(wf, init, fetch=("chi",)).result(120)
     if not telemetry:
@@ -422,13 +425,14 @@ def stat(ev, key):
 def test_cpu_profiler_trace_holds_the_runtime_spans(tmp_path):
     """One run under the real profiler (CPU): the runtime's spans and
     phases are host events on the profiler's clock, and the ``bytes`` of
-    its ``hash`` phases add up to the store's own count."""
+    its ``hash`` phases add up to the store's own count. Memoization
+    demands the digests of the workflow's device-resident values."""
     import jax
     from jax.profiler import ProfileData
     wf, init = tiny_at()
     jax.profiler.start_trace(str(tmp_path))
     try:
-        with EmeraldRuntime(emerald(), max_workers=2) as rt:
+        with EmeraldRuntime(emerald(), max_workers=2, memoize=True) as rt:
             rt.submit(wf, init, fetch=("chi",), prefetch=False).result(120)
             hashed = rt.metrics.snapshot()["mdss.bytes_hashed"]
     finally:
@@ -449,6 +453,25 @@ def test_cpu_profiler_trace_holds_the_runtime_spans(tmp_path):
     assert {stat(ev, "step") for ev in events
             if ev.name == "emerald:exec"} \
         == {"forward", "misfit", "kernel", "update"}
+
+
+def test_at_run_without_memoization_hashes_nothing():
+    """With no digest demanded, the AT workflow's device-resident values
+    are never copied to the host or hashed: no ``d2h`` or ``hash`` phase
+    opens, and every install of one is counted as deferred."""
+    ann = Annotations()
+    tracer = Tracer()
+    tracer.annotation = ann
+    wf, init = tiny_at()
+    with EmeraldRuntime(emerald(), max_workers=2, tracer=tracer) as rt:
+        rt.submit(wf, init, fetch=("chi",)).result(120)
+        snap = rt.metrics.snapshot()
+    assert {"emerald:install", "emerald:ship"} <= ann.names()
+    assert not {"emerald:d2h", "emerald:hash"} & ann.names()
+    assert snap["mdss.bytes_hashed"] == 0
+    assert snap["mdss.manifests_on_demand"] == 0
+    # model and obs at submit, then syn, chi, grad and the new model
+    assert snap["mdss.manifests_deferred"] == 6
 
 
 def test_at_step_programs_bear_their_step_names():

@@ -6,8 +6,11 @@ In one process, for each seed: the cell's set-up and its timed path at the
 cell's own size and load for a window of ``--seconds``, then the numbers
 the run compares, from what the program served and from the driver's
 control (the plain reference in the precision below the configuration's,
-in the program's place), each judged as a run judges it. One JSON line per
-seed.
+in the program's place), each judged as a run judges it, with the driver's notes
+on each comparison. One JSON line per seed.
+
+The readings of a witness (the program with a fault of its own mended,
+``witness.py``) come from the same script run under it.
 """
 from __future__ import annotations
 
@@ -24,9 +27,10 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 from chipbench import harness                                # noqa: E402
 
 
-def readings(checks: dict, failed: int) -> dict:
+def readings(drv, checks: dict, failed: int) -> dict:
     return {"correct": harness.judge(checks, failed),
-            **{k: v for k, (v, _) in checks.items()}}
+            **{k: v for k, (v, _) in checks.items()},
+            "notes": dict(drv.check_notes)}
 
 
 def main() -> int:
@@ -49,10 +53,10 @@ def main() -> int:
             drv.window(harness.Window(args.seconds), lambda: None)
             peak = harness.device_info(devs)["memory_peak_bytes"]
             drv.free()
-            program = readings(drv.check(), drv.failed)
-            control = readings(drv.check(control=True), 0)
+            program = readings(drv, drv.check(), drv.failed)
+            control = readings(drv, drv.check(control=True), 0)
         print(json.dumps({"seed": seed, "program": program,
-                          "control": control, "notes": drv.check_notes,
+                          "control": control,
                           "memory_peak_bytes": peak,
                           "seconds": time.perf_counter() - t0}), flush=True)
         del drv

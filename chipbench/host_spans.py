@@ -47,14 +47,14 @@ class HostSpans:
     t0_ns: float                                   # the mark, trace clock
     t1_ns: float
     iterations: int
-    # (name without the prefix, start ns, end ns, bytes stat or 0)
-    events: List[Tuple[str, float, float, int]] = field(default_factory=list)
+    # (name without the prefix, start ns, end ns)
+    events: List[Tuple[str, float, float]] = field(default_factory=list)
     idle: Optional[Dict[str, float]] = None        # ns, by layer
 
     def clipped(self, names) -> float:
         """Nanoseconds of the named events inside the mark, summed."""
         return sum(min(b, self.t1_ns) - max(a, self.t0_ns)
-                   for n, a, b, _ in self.events
+                   for n, a, b in self.events
                    if n in names and b > self.t0_ns and a < self.t1_ns)
 
 
@@ -70,12 +70,8 @@ def read_xplane(path: str) -> Optional[HostSpans]:
             for ev in line.events:
                 name = ev.name
                 if name.startswith(PREFIX):
-                    short = name[len(PREFIX):]
-                    nbytes = next((int(v) for k, v in ev.stats
-                                   if k == "bytes"), 0) \
-                        if short == "hash" else 0
-                    events.append((short, ev.start_ns,
-                                   ev.start_ns + ev.duration_ns, nbytes))
+                    events.append((name[len(PREFIX):], ev.start_ns,
+                                   ev.start_ns + ev.duration_ns))
                 elif name == ITER_MARK:
                     ends.append(ev.start_ns + ev.duration_ns)
                 elif name == trace_reduce.WINDOW_MARK:
@@ -149,19 +145,6 @@ def span_ms(obs, names) -> Optional[float]:
     return 1e-6 * hs.clipped(names) / hs.iterations
 
 
-def hashed_mb(obs) -> Optional[float]:
-    """The ``bytes`` of the ``hash`` annotations that end inside the mark,
-    per iteration, in units of 1e6 bytes."""
-    hs = load(obs)
-    if hs is None:
-        return None
-    got = [nb for n, _, b, nb in hs.events
-           if n == "hash" and b <= hs.t1_ns]
-    if not got:
-        return None
-    return 1e-6 * sum(got) / hs.iterations
-
-
 def idle_ms(obs, layer: str) -> Optional[float]:
     """Device idle milliseconds per iteration spent under ``layer``."""
     hs = load(obs)
@@ -170,6 +153,6 @@ def idle_ms(obs, layer: str) -> Optional[float]:
         return None
     if hs.idle is None:
         hs.idle = idle_split(
-            [(a, b, layer_of(n)) for n, a, b, _ in hs.events], tr.gaps,
+            [(a, b, layer_of(n)) for n, a, b in hs.events], tr.gaps,
             hs.t0_ns, hs.t1_ns)
     return 1e-6 * hs.idle[layer] / tr.n_devices / hs.iterations

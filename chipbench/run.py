@@ -69,21 +69,6 @@ class Profiler:
         return s
 
 
-class HashCounter:
-    """Bytes the runtime's data store hashes (``MDSS`` content manifests)."""
-
-    def __init__(self):
-        from repro.core import mdss
-        self.bytes = 0
-        inner = mdss.manifest_of
-
-        def counted(value, *a, **kw):
-            self.bytes += mdss.nbytes_of(value)
-            return inner(value, *a, **kw)
-
-        mdss.manifest_of = counted
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -119,13 +104,12 @@ def run_cell(sp, cell, cfg, traffic, *, seed: int, seconds: float,
     served; the benchmark's own runs never set it."""
     import jax
     compiles = harness.CompileCounter()
-    hashed = HashCounter()
     driver = importlib.import_module(f"chipbench.drivers.{traffic['kind']}")
     drv = driver.Driver(cell, cfg, traffic, seed)
     with jax.default_device(devs[0]):
         drv.setup()
         setup_s = time.perf_counter() - T_START
-        c_setup, h_setup = compiles.compiles, hashed.bytes
+        c_setup = compiles.compiles
         prof = Profiler(traffic.get("trace_seconds", seconds)) \
             if trace else None
         if prof:
@@ -135,7 +119,6 @@ def run_cell(sp, cell, cfg, traffic, *, seed: int, seconds: float,
         if prof:
             prof.stop()
         c_window = compiles.compiles - c_setup
-        h_window = hashed.bytes - h_setup
         device = harness.device_info(devs)
         e2e = drv.end_to_end(win)
         obs = harness.Observation(cell=cell, config=cfg, traffic=traffic,
@@ -168,7 +151,6 @@ def run_cell(sp, cell, cfg, traffic, *, seed: int, seconds: float,
     notes = {"setup_s": setup_s, "compiles_in_setup": c_setup,
              "compiles_in_window": c_window,
              "cache_hits": compiles.cache_hits,
-             "bytes_hashed_in_window": h_window,
              "memory_peak_bytes": device["memory_peak_bytes"],
              "calls_in_window": len(obs.calls_of(drv.labels[-1])),
              "check_s": check_s, **drv.check_notes}

@@ -121,6 +121,11 @@ class Driver:
         cfg = self.cfg
         mc = dataclasses.replace(get_config(cfg["arch"]),
                                  **cfg.get("overrides", {}))
+        absent = sorted(a for a in cfg["registry"].values()
+                        if not hasattr(mc, a))
+        if absent:
+            raise ValueError(f"the program's configuration states none of "
+                             f"{absent}, which the file maps its keys to")
         wrong = {k: (getattr(mc, a), cfg[k])
                  for k, a in cfg["registry"].items() if getattr(mc, a) != cfg[k]}
         if wrong:
@@ -252,13 +257,18 @@ class Driver:
     def work(self) -> dict:
         """Model FLOPs of one call of each kind: a prefill runs every
         prompt position through the layers and the head at the last one;
-        a decode runs one token of every slot through both."""
-        flops = getattr(workmodel, f"{self.cfg['reference']}_token_flops")
+        a decode runs one token of every slot through both. And the scan's
+        compulsory bytes in one prefill call."""
+        ref = self.cfg["reference"]
+        flops = getattr(workmodel, f"{ref}_token_flops")
         layers = flops(self.cfg, head=False)
         full = flops(self.cfg, head=True)
+        scan_bytes = getattr(workmodel, f"{ref}_scan_bytes")
         return {"prefill_flops": self.slots * ((self.plen - 1) * layers
                                                + full),
-                "decode_flops": self.slots * full}
+                "decode_flops": self.slots * full,
+                "prefill_scan_bytes": scan_bytes(self.cfg, self.slots,
+                                                 self.plen)}
 
     def spans(self):
         return self.srv.runtime.tracer.spans()

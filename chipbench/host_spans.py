@@ -10,11 +10,13 @@ found on the ``Observation``. A program that opens no such annotation
 leaves nothing to read: every reader then returns None.
 
 "Per iteration" divides by the ``chipbench:at_iter`` annotations that end
-inside the mark. Durations are clipped to the mark. Device idle time is
-``obs.trace.gaps``, already on the host clock; each idle instant goes to
-the layer of the latest-started ``emerald:`` annotation open then on any
-host thread, or to ``unspanned`` where none is open. The four layers
-therefore split exactly the idle time that ``device_idle.at`` measures.
+inside the mark; "per call" by the harness calls of one label that lie
+wholly inside the traced window. Durations are clipped to the mark.
+Device idle time is ``obs.trace.gaps``, already on the host clock; each
+idle instant goes to the layer of the latest-started ``emerald:``
+annotation open then on any host thread, or to ``unspanned`` where none is
+open. The four layers therefore split exactly the idle time that
+``device_idle.at`` measures.
 """
 from __future__ import annotations
 
@@ -60,7 +62,7 @@ class HostSpans:
 
 def read_xplane(path: str) -> Optional[HostSpans]:
     """The marked window's iterations and ``emerald:`` events; None where
-    the trace holds no mark, no iteration or no such event."""
+    the trace holds no mark or no such event."""
     from jax.profiler import ProfileData
     win, ends, events = None, [], []
     for plane in ProfileData.from_file(path).planes:
@@ -81,7 +83,7 @@ def read_xplane(path: str) -> Optional[HostSpans]:
     a0, a1 = win
     n = sum(1 for t in ends if a0 < t <= a1)
     events = [e for e in events if e[2] > a0 and e[1] < a1]
-    if n == 0 or not events:
+    if not events:
         return None
     return HostSpans(a0, a1, n, events)
 
@@ -140,16 +142,28 @@ def load(obs) -> Optional[HostSpans]:
 def span_ms(obs, names) -> Optional[float]:
     """Milliseconds of the named annotations per iteration."""
     hs = load(obs)
-    if hs is None or not any(e[0] in names for e in hs.events):
+    if hs is None or hs.iterations == 0 \
+            or not any(e[0] in names for e in hs.events):
         return None
     return 1e-6 * hs.clipped(names) / hs.iterations
+
+
+def per_call_ms(obs, names, label: str) -> Optional[float]:
+    """Milliseconds of the named annotations in the traced window per
+    ``label`` call wholly inside it."""
+    hs = load(obs)
+    calls = len(obs.calls_of(label, traced=True))
+    if hs is None or calls == 0 \
+            or not any(e[0] in names for e in hs.events):
+        return None
+    return 1e-6 * hs.clipped(names) / calls
 
 
 def idle_ms(obs, layer: str) -> Optional[float]:
     """Device idle milliseconds per iteration spent under ``layer``."""
     hs = load(obs)
     tr = obs.trace
-    if hs is None or tr.n_devices == 0:
+    if hs is None or hs.iterations == 0 or tr.n_devices == 0:
         return None
     if hs.idle is None:
         hs.idle = idle_split(

@@ -1,0 +1,8 @@
+"""Execution per prefill call of a serving cell: the ring's ``exec`` spans
+of the call's run, each ending in ``block_until_ready``. Moves
+``serve_tok_s``."""
+from chipbench.metrics_common import span_ms
+
+
+def read(obs):
+    return span_ms(obs, "prefill", ("exec",))

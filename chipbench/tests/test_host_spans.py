@@ -68,6 +68,30 @@ def test_readers_per_iteration():
     assert host_spans.idle_ms(obs, "exec") == 0
 
 
+def test_per_call_reads_every_annotation_over_whole_calls():
+    from chipbench.harness import Call
+    win = Window(10.0)
+    t0 = win.t0
+    obs = Observation(cell={"name": "c"}, config={}, traffic={}, window=win)
+    obs.trace = trace_reduce.Summary(window_s=1.0, busy_s=0.9, n_devices=1,
+                                     t0_ns=0)
+    obs.trace.t0, obs.trace.t1 = t0, t0 + 1.0
+    obs.calls = [Call("decode", t0 + 0.1, t0 + 0.2),
+                 Call("decode", t0 + 0.3, t0 + 0.4),
+                 Call("decode", t0 + 0.9, t0 + 1.1),   # ends after the trace
+                 Call("prefill", t0, t0 + 0.05)]
+    obs._host_spans = HostSpans(t0_ns=0, t1_ns=1e9, iterations=0, events=[
+        ("deliver", 0.0, 2e6), ("deliver", 3e6, 4e6),
+        ("deliver", 999e6, 1002e6), ("submit", 0.0, 1e6)])
+    assert host_spans.per_call_ms(obs, ("deliver",), "decode") \
+        == pytest.approx(4 / 2)
+    assert host_spans.per_call_ms(obs, ("dispatch",), "decode") is None
+    assert host_spans.per_call_ms(obs, ("deliver",), "at_iter") is None
+    # no iteration marks: the per-iteration readers find nothing
+    assert host_spans.span_ms(obs, ("submit",)) is None
+    assert host_spans.idle_ms(obs, "runtime") is None
+
+
 def test_cpu_traced_run_reports_the_host_span_metrics():
     """A traced run of the AT cell on the CPU: the program's annotations
     are found, one iteration per call. MDSS hashes nothing in this cell

@@ -20,6 +20,18 @@ CHAT_END_TO_END = [
 CHAT_PER_LAYER = [
     {"name": "serve_mfu", "unit": "%", "better": "higher",
      "source": "host_clock", "layer": "whole step", "moves": "serve_tok_s"},
+    {"name": "prefill_exec_ms.chat", "unit": "ms", "better": "lower",
+     "source": "program_span", "layer": "execution", "moves": "serve_tok_s"},
+    {"name": "decode_exec_ms.chat", "unit": "ms", "better": "lower",
+     "source": "program_span", "layer": "execution", "moves": "itl_p90_ms"},
+    {"name": "decode_submit_ms.chat", "unit": "ms", "better": "lower",
+     "source": "program_span", "layer": "runtime", "moves": "itl_p90_ms"},
+    {"name": "deliver_ms.chat", "unit": "ms", "better": "lower",
+     "source": "program_span", "layer": "serving", "moves": "itl_p90_ms"},
+    {"name": "device_idle.chat", "unit": "%", "better": "lower",
+     "source": "device_trace", "layer": "device", "moves": "itl_p90_ms"},
+    {"name": "scan_roofline.chat", "unit": "%", "better": "higher",
+     "source": "device_trace", "layer": "kernel", "moves": "serve_tok_s"},
 ]
 
 PEAKS = {"bf16_flops": 197e12, "int8_ops": 393e12,

@@ -43,3 +43,28 @@ def mamba1_token_flops(cfg: dict, *, head: bool) -> float:
     if head:
         total += 2 * d * cfg["vocab_size"]
     return float(total)
+
+
+DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def mamba1_scan_bytes(cfg: dict, batch: int, length: int) -> float:
+    """Compulsory HBM bytes of the selective scan over one prefill call of
+    ``batch`` sequences of ``length`` positions, through every layer.
+
+    Per layer: the convolved input ``x``, the step ``Delta`` (batch x
+    length x di each) and ``B`` and ``C`` (batch x length x N each) read
+    once, the output ``y`` (batch x length x di) written once, all at the
+    configuration's activation width; the state read once (``h0``) and
+    written once (``h_last``), batch x di x N in float32; ``A`` (di x N)
+    and ``D`` (di) read once, in float32. The state of every step stays on
+    the chip, and ``Delta`` is counted at the activation width though a
+    program may keep it in float32, so the count is a floor: a kernel's
+    share of its roofline by these bytes cannot pass 100%.
+    """
+    di, n = cfg["intermediate_size"], cfg["state_size"]
+    act = DTYPE_BYTES[cfg["torch_dtype"]]
+    tokens = batch * length
+    layer = (act * tokens * (3 * di + 2 * n)
+             + 4 * (2 * batch * di * n + di * n + di))
+    return float(cfg["num_hidden_layers"] * layer)

@@ -1,14 +1,19 @@
 """Adjoint Tomography — the paper's evaluation application (§4), for real.
 
 A 3D acoustic wave-equation solver (2nd-order leapfrog finite differences,
-``lax.scan`` over timesteps with rematerialization) plus the four AT steps
-from the paper:
+``kernels/leapfrog``) plus the four AT steps from the paper. The solver
+takes one of two paths, chosen from its input: on a TPU in float32, two
+Pallas kernels that keep the wavefields in VMEM for the whole time loop,
+the forward solve and its discrete adjoint, joined by a ``custom_vjp``;
+elsewhere (the CPU, float64, meshes too large for VMEM) a ``lax.scan``
+over timesteps with rematerialization. The steps:
 
   1. build starting model, compute synthetic seismograms       (local)
   2. misfit between synthetics and observations                (remotable)
   3. Fréchet kernel — gradient of misfit w.r.t. the model      (remotable)
-     (the "adjoint" computation; here literally the adjoint-state method
-     obtained by reverse-mode AD through the wave solver)
+     (the "adjoint" computation: the adjoint-state method, run by the
+     adjoint kernel on the VMEM path and by reverse-mode AD through the
+     scan on the XLA path)
   4. model update                                              (remotable)
 
 Steps 2–4 carry the paper's ``remotable`` annotation; iterating the
@@ -28,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.workflow import Workflow
+from repro.kernels import leapfrog
 
 
 @dataclass(frozen=True)
@@ -56,24 +62,6 @@ FIG12 = ATConfig(nx=208, ny=44, nz=46)
 # Wave physics
 # ---------------------------------------------------------------------------
 
-def _shift(u: jnp.ndarray, axis: int, d: int) -> jnp.ndarray:
-    """Shift with zero boundaries (Dirichlet), no wraparound."""
-    pad = [(0, 0)] * u.ndim
-    pad[axis] = (max(d, 0), max(-d, 0))
-    up = jnp.pad(u, pad)
-    idx = [slice(None)] * u.ndim
-    idx[axis] = slice(max(-d, 0), up.shape[axis] - max(d, 0))
-    return up[tuple(idx)]
-
-
-def _laplacian(u: jnp.ndarray, dx: float) -> jnp.ndarray:
-    """7-point 3D Laplacian, zero (Dirichlet) boundaries."""
-    lap = -6.0 * u
-    for axis in range(3):
-        lap = lap + _shift(u, axis, 1) + _shift(u, axis, -1)
-    return lap / (dx * dx)
-
-
 def _ricker(cfg: ATConfig) -> jnp.ndarray:
     t = jnp.arange(cfg.nt) * cfg.dt - 1.0 / cfg.f0
     a = (math.pi * cfg.f0) ** 2 * t ** 2
@@ -88,23 +76,9 @@ def _receiver_idx(cfg: ATConfig) -> Tuple[jnp.ndarray, int, int]:
 @partial(jax.jit, static_argnums=(1,))
 def simulate(c: jnp.ndarray, cfg: ATConfig) -> jnp.ndarray:
     """Leapfrog acoustic FD; returns seismograms (nt, n_receivers)."""
-    src = _ricker(cfg)
-    sx, sy, sz = cfg.nx // 2, cfg.ny // 2, 2
-    rx, ry, rz = _receiver_idx(cfg)
-    c2dt2 = (c * cfg.dt) ** 2
-
-    def step(carry, s_t):
-        u_prev, u = carry
-        lap = _laplacian(u, cfg.dx)
-        u_next = 2 * u - u_prev + c2dt2 * lap
-        u_next = u_next.at[sx, sy, sz].add(c2dt2[sx, sy, sz] * s_t)
-        rec = u_next[rx, ry, rz]
-        return (u, u_next), rec
-
-    u0 = jnp.zeros((cfg.nx, cfg.ny, cfg.nz))
-    step = jax.checkpoint(step)
-    (_, _), seis = jax.lax.scan(step, (u0, u0), src)
-    return seis
+    return leapfrog.seismograms(
+        c, _ricker(cfg), dt=cfg.dt, dx=cfg.dx,
+        source=(cfg.nx // 2, cfg.ny // 2, 2), receivers=_receiver_idx(cfg))
 
 
 def starting_model(cfg: ATConfig) -> jnp.ndarray:

@@ -197,6 +197,8 @@ REGISTRY = MetricsRegistry()
 #: on any dotted metric name missing from this table — same contract as
 #: ``EVENT_SCHEMA`` for event kinds.
 METRIC_CATALOG: Dict[str, str] = {
+    "at.wave_solver.vmem": "AT wave solves traced onto the VMEM kernels.",
+    "at.wave_solver.xla": "AT wave solves traced onto the XLA scan.",
     "autoscaler.desired_workers": "Autoscaler's current target pool size.",
     "autoscaler.scale_ups": "Scale-up decisions taken.",
     "autoscaler.scale_downs": "Scale-down decisions taken.",

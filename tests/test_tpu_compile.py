@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels.flash_attention.ops import flash_attention_kernel_call
+from repro.kernels.leapfrog import kernel as leapfrog
 from repro.kernels.mamba_scan.kernel import selective_scan_fwd
 
 KERNEL = "tpu_custom_call"
@@ -64,4 +65,23 @@ def test_selective_scan_compiles_for_v5e(one_chip):
         (Bt, L, di), (Bt, L, di), (di, N), (Bt, L, N), (Bt, L, N), (di,),
         (Bt, di, N))]
     compiled = jax.jit(selective_scan_fwd).lower(*args).compile()
+    assert KERNEL in compiled.as_text()
+
+
+@pytest.mark.parametrize("solve", ["forward", "forward+history", "adjoint"])
+def test_leapfrog_kernels_compile_for_v5e(one_chip, solve):
+    """The adjoint-tomography wave solver at the paper's Fig. 12 mesh
+    (208x44x46, nt 200), with its VMEM limit."""
+    nt = 200
+    g = leapfrog.Grid(46, 44, 208, (2, 22, 104), (2, 22), 100.0)
+    k = _spec((g.nz, g.ny_p, g.nx_p), jnp.float32, one_chip)
+    if solve == "adjoint":
+        rows = _spec((nt, g.nx_p), jnp.float32, one_chip)
+        hist = _spec((nt, g.nz, g.ny_p, g.nx_p), jnp.float32, one_chip)
+        compiled = jax.jit(lambda k, r, h: leapfrog.leapfrog_adj(
+            k, r, h, grid=g)).lower(k, rows, hist).compile()
+    else:
+        f = _spec((nt,), jnp.float32, one_chip)
+        compiled = jax.jit(lambda k, f: leapfrog.leapfrog_fwd(
+            k, f, grid=g, history=solve != "forward")).lower(k, f).compile()
     assert KERNEL in compiled.as_text()

@@ -56,12 +56,16 @@ lands. A per-run completion fence keeps ``result()`` from resolving
 before the run's final checkpoint is durable, and a failed write still
 fails that run (durability contract) without stalling other tenants.
 
-Admission control: when the shared store carries a ``capacity_bytes``
-ceiling, ``submit`` refuses new runs (:class:`AdmissionRefused`) once
-residency crosses ``admission_headroom`` x capacity — backpressure at
-the front door instead of an OOM mid-run. Per-run residency budgets
-(``submit(residency_budget={...})``) bound a tenant's footprint per tier
-with MDSS-side LRU eviction.
+Admission: ``submit`` resolves its arguments once into a
+``_Submission`` record. One check under ``_runs_lock``
+(``_refusal_locked``) refuses it when residency is past
+``admission_headroom`` x ``capacity_bytes``, every ``max_active_runs``
+slot is busy, or its declared ``residency_budget`` does not fit the
+remaining capacity: :class:`AdmissionRefused`, or with ``park=True`` a
+queued record that the driver admits once the same check passes. Either
+way the verifier runs once before any run state lands; then ``_admit``
+sets the namespace budgets (MDSS evicts LRU past them) and materializes
+the run.
 """
 from __future__ import annotations
 
@@ -297,6 +301,35 @@ class RunHandle:
 # --------------------------------------------------------------------------
 # internal per-run state
 # --------------------------------------------------------------------------
+@dataclass(frozen=True)
+class _Submission:
+    """One ``submit`` call, resolved once: everything admission and
+    ``_materialize`` read, whether the run is admitted at once or parked
+    and drained later. ``speculate_after`` and ``prefetch`` hold the
+    runtime defaults already applied."""
+    wf: Workflow
+    run_id: str
+    ns: str
+    mdss: Any                       # NamespacedMDSS or base MDSS
+    init_vars: Optional[Dict[str, Any]]
+    residency_budget: Optional[Dict[str, int]]
+    declared: int                   # sum of residency_budget, 0 if none
+    policy: Optional[str]
+    fetch: Any
+    resume: bool
+    weight: float
+    priority: int
+    speculate_after: Optional[float]
+    prefetch: bool
+    checkpointer: Optional[RunCheckpointer]
+    validate: str
+    on_done: Any
+    events: List[Event]
+    deadline_s: Optional[float]
+    deadline_perf: Optional[float]
+    slo_ms: Optional[float]
+
+
 @dataclass
 class _Run:
     run_id: str
@@ -309,14 +342,9 @@ class _Run:
     order_idx: Dict[str, int]
     completed: set
     mdss: Any                       # NamespacedMDSS or base MDSS
-    policy: Any
-    fetch: Any
+    sub: _Submission                # the run's options, as submitted
+    policy: Any                     # built from sub.policy
     checkpointer: Optional[RunCheckpointer]
-    weight: float
-    priority: int
-    speculate_after: Optional[float]
-    prefetch: bool
-    events: List[Event]
     lock: threading.Lock = field(default_factory=threading.Lock)
     ready: Dict[bool, list] = field(
         default_factory=lambda: {True: [], False: []})   # keyed by offloaded?
@@ -330,8 +358,7 @@ class _Run:
     retries: int = 0
     # wall/monotonic epoch pair fixed at submission: every event's
     # t_wall = epoch_wall + (t - epoch_perf), so driver events land on
-    # the same epoch timeline as worker-reported phases (satellite: the
-    # old perf_counter-only Event was incomparable across processes)
+    # the same epoch timeline as worker-reported phases
     epoch_wall: float = field(default_factory=time.time)
     epoch_perf: float = field(default_factory=time.perf_counter)
     root_ctx: Any = None            # (trace_id, span_id) of the run span
@@ -341,12 +368,9 @@ class _Run:
     # gather completes. fanout_t0 holds the matching wall start.
     fanout_ctx: Dict[str, Any] = field(default_factory=dict)
     fanout_t0: Dict[str, float] = field(default_factory=dict)
-    # serving-front-door state: an absolute perf_counter deadline plus a
-    # per-run SLO (ms). When the deadline's slack shrinks below the SLO
-    # while ready work is still waiting for a lane, the driver preempts
-    # the longest-running preemptible batch task (once per run).
-    slo_ms: Optional[float] = None
-    deadline_perf: Optional[float] = None
+    # serving front door: when the slack to sub.deadline_perf shrinks
+    # below sub.slo_ms while ready work still waits for a lane, the
+    # driver preempts the longest-running preemptible batch task, once
     preempt_fired: bool = False
     # seconds this run waited parked; credited as a fair-share deficit at
     # admission so near-SLO latecomers overtake long-resident tenants
@@ -355,43 +379,22 @@ class _Run:
     def emit(self, kind, step, tier="", **info):
         t = time.perf_counter()
         with self.lock:
-            self.events.append(Event(kind, step, tier, t, info,
-                                     self.epoch_wall + (t - self.epoch_perf)))
+            self.sub.events.append(Event(
+                kind, step, tier, t, info,
+                self.epoch_wall + (t - self.epoch_perf)))
 
 
 @dataclass
 class _Parked:
-    """One submission waiting in the front door's admission queue.
-
-    Everything ``_materialize`` needs to turn it into a live ``_Run`` is
-    carried here verbatim from ``submit``; validation already ran at park
-    time (a rejected workflow is refused immediately, it never parks),
-    and NO runtime state — reservations, namespace budgets, init_vars —
-    lands until admission, so cancelling or failing a parked entry needs
-    no rollback (the symmetric-release contract the admission paths
-    share)."""
+    """A validated submission waiting in the front door's admission
+    queue. It holds no runtime state — no reservation, namespace budget
+    or init_vars put lands until the drain loop admits it — so cancelling
+    or failing a parked entry needs no rollback."""
+    sub: _Submission
     handle: RunHandle
-    pwf: PartitionedWorkflow
-    wf: Workflow
-    run_id: str
-    ns: str
-    mdss: Any
-    init_vars: Optional[Dict[str, Any]]
-    residency_budget: Optional[Dict[str, int]]
-    declared: int
-    policy: Optional[str]
-    fetch: Any
-    resume: bool
-    weight: float
-    priority: int
-    speculate_after: Any
-    prefetch: Optional[bool]
-    checkpointer: Optional[RunCheckpointer]
-    reason: str                     # capacity | budget | run_slots
+    reason: str                     # capacity | run_slots | budget
     seq: int                        # FIFO tiebreak among equal deadlines
     parked_t: float                 # perf_counter at park time
-    slo_ms: Optional[float] = None
-    deadline_perf: Optional[float] = None
     preempt_fired: bool = False
 
 
@@ -399,8 +402,8 @@ def _park_order(p: _Parked) -> tuple:
     """Drain order: oldest (smallest) absolute deadline first, then FIFO.
     Strict head-of-queue admission — a later small run never bypasses the
     head (that bypass is exactly the H125 starvation shape)."""
-    return (p.deadline_perf if p.deadline_perf is not None else float("inf"),
-            p.seq)
+    deadline = p.sub.deadline_perf
+    return (deadline if deadline is not None else float("inf"), p.seq)
 
 
 _AUTO = object()
@@ -609,14 +612,6 @@ class EmeraldRuntime:
         """
         if self._closed:
             raise RuntimeClosed("runtime is closed")
-        park_reason = None
-        if self.mdss.over_capacity(self.admission_headroom):
-            if not park:
-                raise AdmissionRefused(
-                    f"shared store holds {self.mdss.resident_bytes()} of "
-                    f"{self.mdss.capacity_bytes} capacity bytes (headroom "
-                    f"{self.admission_headroom:.0%}): submission refused")
-            park_reason = "capacity"
         if resume and namespace is None:
             # a fresh auto namespace has no prior state OR checkpoint to
             # resume from — silently re-running the whole DAG (including
@@ -635,99 +630,68 @@ class EmeraldRuntime:
                               trace_id=run_id, parent=(run_id, 0)):
             pwf = workflow if isinstance(workflow, PartitionedWorkflow) \
                 else partition(workflow)
-            wf = pwf.workflow
             ns = f"run{n}" if namespace is None else namespace
-            mdss = self.mdss if ns == "" else self.mdss.namespaced(
-                ns, shared=self.shared_namespace)
             if residency_budget and not ns:
                 raise ValueError(
                     "residency_budget needs a namespaced run (an "
                     "un-namespaced submission shares the base store)")
-            declared = sum(residency_budget.values()) \
-                if residency_budget else 0
-            deadline_perf = None if deadline_s is None \
-                else time.perf_counter() + deadline_s
-            limit = self.admission_headroom * self.mdss.capacity_bytes \
-                if declared and self.mdss.capacity_bytes else None
+            sub = _Submission(
+                wf=pwf.workflow, run_id=run_id, ns=ns,
+                mdss=self.mdss if ns == "" else self.mdss.namespaced(
+                    ns, shared=self.shared_namespace),
+                init_vars=init_vars, residency_budget=residency_budget,
+                declared=sum((residency_budget or {}).values()),
+                policy=policy, fetch=fetch, resume=resume, weight=weight,
+                priority=priority,
+                speculate_after=self.speculate_after
+                if speculate_after is _AUTO else speculate_after,
+                prefetch=self.prefetch if prefetch is None else prefetch,
+                checkpointer=checkpointer, validate=validate,
+                on_done=on_done, events=[] if events is None else events,
+                deadline_s=deadline_s,
+                deadline_perf=None if deadline_s is None
+                else time.perf_counter() + deadline_s,
+                slo_ms=slo_ms)
             with self._runs_lock:
-                if self.max_active_runs is not None \
-                        and self._live >= self.max_active_runs:
-                    if not park:
-                        raise AdmissionRefused(
-                            f"{self._live} of {self.max_active_runs} run "
-                            "slots busy: submission refused")
-                    park_reason = park_reason or "run_slots"
-                if limit is not None and park_reason is None:
-                    # check + reserve atomically: two concurrent submits
-                    # that each fit alone but not together must not both
-                    # pass. An admitted run's unfilled declared budget is
-                    # capacity it may still legitimately consume.
-                    reserved = sum(
-                        max(0, decl
-                            - self.mdss.namespace_resident_bytes(rns))
-                        for rns, decl in self._reserved.values())
-                    committed = self.mdss.resident_bytes() + reserved
-                    if committed + declared > limit:
-                        if not park:
-                            raise AdmissionRefused(
-                                f"declared residency budget {declared} does "
-                                f"not fit remaining capacity ({committed} of "
-                                f"{limit:.0f} already committed by residency "
-                                "+ admitted budgets)")
-                        park_reason = "budget"
-                if park_reason is None:
-                    if limit is not None:
-                        self._reserved[run_id] = (ns, declared)
-                    self._live += 1
-            if park_reason is not None:
-                return self._park(
-                    pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
-                    declared, policy, fetch, resume, weight, priority,
-                    speculate_after, prefetch, checkpointer, events, on_done,
-                    validate, park_reason, deadline_s, deadline_perf, slo_ms)
+                refusal = self._refusal_locked(sub.declared)
+                if refusal is None:
+                    self._reserve_locked(sub)
+                elif not park:
+                    raise AdmissionRefused(refusal[1])
+            if refusal is not None:
+                return self._park(sub, self._open_handle(sub), refusal[0])
             try:
-                return self._submit_admitted(
-                    pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
-                    policy, fetch, resume, weight, priority, speculate_after,
-                    prefetch, checkpointer, events, on_done, validate,
-                    slo_ms=slo_ms, deadline_perf=deadline_perf)
+                handle = self._open_handle(sub)
+                self._admit(sub, handle)
+                return handle
             except BaseException:
                 # anything that fails between admission and the driver
-                # taking ownership must release the reservation — a leak
-                # here would shrink admission capacity forever. The run-slot
-                # count releases symmetrically (same lock, same path) so a
-                # rejected submission can never wedge the front door shut.
+                # taking ownership must release the run slot and
+                # reservation — a leak here would shrink admission
+                # capacity forever
                 with self._runs_lock:
-                    self._reserved.pop(run_id, None)
-                    self._live -= 1
+                    self._release_locked(run_id)
                 raise
 
-    def _park(self, pwf, wf, run_id, ns, mdss, init_vars, residency_budget,
-              declared, policy, fetch, resume, weight, priority,
-              speculate_after, prefetch, checkpointer, events, on_done,
-              validate, reason, deadline_s, deadline_perf, slo_ms
-              ) -> RunHandle:
-        """Park a submission the capacity checks refused. Validation runs
-        FIRST — before the entry lands anywhere — so a rejected workflow
-        is refused outright and a parked entry needs no rollback ever:
-        no reservation, namespace budget, or init_vars put exists until
-        the drain loop admits it."""
-        findings = self._validate_submission(
-            wf, mdss, init_vars, residency_budget, resume, validate)
-        sink = events if events is not None else []
-        handle = RunHandle(run_id, ns, self, sink)
+    def _open_handle(self, sub: _Submission) -> RunHandle:
+        """Validate ``sub`` — once, before any of its state lands — and
+        open its handle."""
+        findings = self._validate_submission(sub)
+        handle = RunHandle(sub.run_id, sub.ns, self, sub.events)
         handle.findings = findings
-        handle._on_done = on_done
-        handle.trace_id = run_id
+        # installed before the run can possibly finalize — no TOCTOU
+        handle._on_done = sub.on_done
+        handle.trace_id = sub.run_id
+        return handle
+
+    def _park(self, sub: _Submission, handle: RunHandle,
+              reason: str) -> RunHandle:
+        """Queue a validated submission that the admission check refused.
+        Nothing of it lands until the drain loop admits it."""
         handle._parked = True
-        entry = _Parked(
-            handle=handle, pwf=pwf, wf=wf, run_id=run_id, ns=ns, mdss=mdss,
-            init_vars=init_vars, residency_budget=residency_budget,
-            declared=declared, policy=policy, fetch=fetch, resume=resume,
-            weight=weight, priority=priority, speculate_after=speculate_after,
-            prefetch=prefetch, checkpointer=checkpointer, reason=reason,
-            seq=next(self._park_seq), parked_t=time.perf_counter(),
-            slo_ms=slo_ms, deadline_perf=deadline_perf)
+        entry = _Parked(sub=sub, handle=handle, reason=reason,
+                        seq=next(self._park_seq),
+                        parked_t=time.perf_counter())
         with self._runs_lock:
             if len(self._parked) >= self.park_limit:
                 self.metrics.inc("frontdoor.queue_full")
@@ -738,12 +702,12 @@ class EmeraldRuntime:
             depth = len(self._parked)
             self.parked_total += 1
         info = {"reason": reason, "depth": depth}
-        if deadline_s is not None:
-            info["deadline_s"] = deadline_s
-        if slo_ms is not None:
-            info["slo_ms"] = slo_ms
-        t = time.perf_counter()
-        sink.append(Event("park", "<workflow>", "", t, info, time.time()))
+        if sub.deadline_s is not None:
+            info["deadline_s"] = sub.deadline_s
+        if sub.slo_ms is not None:
+            info["slo_ms"] = sub.slo_ms
+        sub.events.append(Event("park", "<workflow>", "", time.perf_counter(),
+                                info, time.time()))
         # wake the driver for an immediate drain attempt (capacity may
         # already suffice — e.g. park under run-slot pressure that a
         # finalize just relieved)
@@ -753,51 +717,33 @@ class EmeraldRuntime:
             self._fail_parked(RuntimeClosed("runtime closed"))
         return handle
 
-    def _submit_admitted(self, pwf, wf, run_id, ns, mdss, init_vars,
-                         residency_budget, policy, fetch, resume, weight,
-                         priority, speculate_after, prefetch, checkpointer,
-                         events, on_done, validate="error", slo_ms=None,
-                         deadline_perf=None) -> RunHandle:
-        if residency_budget:
-            for tier_name, max_bytes in residency_budget.items():
-                self.mdss.set_namespace_budget(ns, tier_name, max_bytes)
+    def _admit(self, sub: _Submission, handle: RunHandle) -> "_Run":
+        """Turn an admitted submission into a live run: set its namespace
+        budgets, materialize it, and clear the budgets again if either
+        fails. The caller holds the run's slot and reservation."""
         try:
-            findings = self._validate_submission(
-                wf, mdss, init_vars, residency_budget, resume, validate)
+            for tier_name, max_bytes in (sub.residency_budget or {}).items():
+                self.mdss.set_namespace_budget(sub.ns, tier_name, max_bytes)
+            return self._materialize(sub, handle)
         except BaseException:
-            # a rejected submission must leave no trace: clear the
-            # budgets this call just configured (nothing else landed yet
-            # — validation runs before the init_vars puts)
-            for tier_name in (residency_budget or ()):
-                self.mdss.set_namespace_budget(ns, tier_name, None)
+            for tier_name in (sub.residency_budget or ()):
+                self.mdss.set_namespace_budget(sub.ns, tier_name, None)
             raise
-        sink = events if events is not None else []
-        handle = RunHandle(run_id, ns, self, sink)
-        handle.findings = findings
-        # installed before the run can possibly finalize — no TOCTOU
-        handle._on_done = on_done
-        handle.trace_id = run_id
-        self._materialize(pwf, wf, run_id, ns, mdss, init_vars, resume,
-                          policy, fetch, weight, priority, speculate_after,
-                          prefetch, checkpointer, handle, sink, slo_ms,
-                          deadline_perf)
-        return handle
 
-    def _materialize(self, pwf, wf, run_id, ns, mdss, init_vars, resume,
-                     policy, fetch, weight, priority, speculate_after,
-                     prefetch, checkpointer, handle, sink, slo_ms,
-                     deadline_perf) -> "_Run":
+    def _materialize(self, sub: _Submission, handle: RunHandle) -> "_Run":
+        wf, mdss = sub.wf, sub.mdss
         completed: set = set()
         with self.tracer.phase("materialize"):
-            for uri, val in (init_vars or {}).items():
+            for uri, val in (sub.init_vars or {}).items():
                 if uri not in wf.variables:
                     wf.var(uri)
                 mdss.put(uri, val, tier="local")
+        checkpointer = sub.checkpointer
         if checkpointer is None and self.checkpoint_dir:
             checkpointer = RunCheckpointer(
                 mdss, wf, self.checkpoint_dir,
-                ckpt_name=f"{ns}.{wf.name}" if ns else wf.name)
-        if resume and checkpointer is not None:
+                ckpt_name=f"{sub.ns}.{wf.name}" if sub.ns else wf.name)
+        if sub.resume and checkpointer is not None:
             state = checkpointer._load_checkpoint()
             if state is not None:
                 completed = set(state["completed"])
@@ -825,7 +771,7 @@ class EmeraldRuntime:
         succs = wf.successors(deps=deps)
         indeg = wf.in_degrees(completed, deps=deps)
         order_idx = {nm: i for i, nm in enumerate(wf.order)}
-        run_policy = make_policy(policy or self.default_policy,
+        run_policy = make_policy(sub.policy or self.default_policy,
                                  self.manager.cost_model, mdss,
                                  self.cloud_tier)
         if hasattr(run_policy, "set_priorities"):
@@ -834,18 +780,13 @@ class EmeraldRuntime:
 
         # one trace per run: the root "run" span's identity is allocated
         # now (so every child can parent to it) and recorded at finalize
-        root_ctx = (run_id, self.tracer.next_id()) \
+        root_ctx = (sub.run_id, self.tracer.next_id()) \
             if self.tracer.enabled else None
-        run = _Run(run_id=run_id, ns=ns, handle=handle, wf=wf, steps=steps,
-                   succs=succs, indeg=indeg, order_idx=order_idx,
-                   completed=completed, mdss=mdss, policy=run_policy,
-                   fetch=fetch, checkpointer=checkpointer, weight=weight,
-                   priority=priority,
-                   speculate_after=self.speculate_after
-                   if speculate_after is _AUTO else speculate_after,
-                   prefetch=self.prefetch if prefetch is None else prefetch,
-                   events=sink, root_ctx=root_ctx, slo_ms=slo_ms,
-                   deadline_perf=deadline_perf)
+        run = _Run(run_id=sub.run_id, ns=sub.ns, handle=handle, wf=wf,
+                   steps=steps, succs=succs, indeg=indeg,
+                   order_idx=order_idx, completed=completed, mdss=mdss,
+                   sub=sub, policy=run_policy, checkpointer=checkpointer,
+                   root_ctx=root_ctx)
         handle.epoch_wall = run.epoch_wall
         if checkpointer is not None:
             checkpointer._emit = run.emit
@@ -857,11 +798,12 @@ class EmeraldRuntime:
             self._flush_orphaned_inbox()
         return run
 
-    def _validate_submission(self, wf, mdss, init_vars, residency_budget,
-                             resume, validate):
-        """Admission-time static verification (repro.analysis). Runs
-        before ANY submission state lands (budgets, init_vars puts), so
-        a rejection leaves the runtime and store untouched."""
+    def _validate_submission(self, sub: _Submission):
+        """Admission-time static verification (repro.analysis), run once
+        per submission by ``_open_handle`` before its budgets, init_vars
+        or parking-queue entry land, so a rejection leaves the store
+        untouched."""
+        validate, wf = sub.validate, sub.wf
         if validate not in ("error", "warn", "off"):
             raise ValueError(
                 f"validate must be 'error', 'warn' or 'off', "
@@ -870,16 +812,16 @@ class EmeraldRuntime:
             return []
         from repro.analysis.verifier import WorkflowRejected, verify
         provided = None
-        if not resume:
+        if not sub.resume:
             # the bound set: explicit init vars plus whatever is already
             # resident for this run's namespace (warm resubmission /
             # shared-namespace fall-through)
-            provided = set(init_vars or ())
+            provided = set(sub.init_vars or ())
             provided |= {u for u in wf.variables
-                         if u not in provided and mdss.version(u)}
+                         if u not in provided and sub.mdss.version(u)}
         with self.tracer.phase("verify"):
             findings = verify(wf, provided=provided,
-                              residency_budget=residency_budget,
+                              residency_budget=sub.residency_budget,
                               tiers=self.manager.tiers,
                               capacity_bytes=self.mdss.capacity_bytes)
         errors = [f for f in findings if f.severity == "error"]
@@ -903,23 +845,51 @@ class EmeraldRuntime:
         if not self._closed and self._driver.is_alive():
             self._inbox.put(("nudge",))
 
-    def _fits_locked(self, declared: int) -> bool:
-        """Would a submission with ``declared`` budget bytes be admitted
-        right now? Caller holds ``_runs_lock`` (same atomic
-        check-then-reserve discipline as ``submit``)."""
+    def _refusal_locked(self, declared: int) -> Optional[tuple]:
+        """The admission check, shared by ``submit`` and the drain loop;
+        the caller holds ``_runs_lock`` and reserves under the same hold,
+        so two submissions that each fit alone but not together cannot
+        both pass. Returns ``(reason, message)`` for the first check a
+        submission declaring ``declared`` budget bytes fails — capacity,
+        then run_slots, then budget — or None when it fits now."""
+        mdss = self.mdss
+        if mdss.over_capacity(self.admission_headroom):
+            return ("capacity",
+                    f"shared store holds {mdss.resident_bytes()} of "
+                    f"{mdss.capacity_bytes} capacity bytes (headroom "
+                    f"{self.admission_headroom:.0%}): submission refused")
         if self.max_active_runs is not None \
                 and self._live >= self.max_active_runs:
-            return False
-        if self.mdss.over_capacity(self.admission_headroom):
-            return False
-        if declared and self.mdss.capacity_bytes:
-            limit = self.admission_headroom * self.mdss.capacity_bytes
-            reserved = sum(
-                max(0, decl - self.mdss.namespace_resident_bytes(rns))
+            return ("run_slots", f"{self._live} of {self.max_active_runs} "
+                    "run slots busy: submission refused")
+        if declared and mdss.capacity_bytes:
+            # an admitted run's unfilled declared budget is capacity it
+            # may still legitimately consume
+            limit = self.admission_headroom * mdss.capacity_bytes
+            committed = mdss.resident_bytes() + sum(
+                max(0, decl - mdss.namespace_resident_bytes(rns))
                 for rns, decl in self._reserved.values())
-            if self.mdss.resident_bytes() + reserved + declared > limit:
-                return False
-        return True
+            if committed + declared > limit:
+                return ("budget",
+                        f"declared residency budget {declared} does not "
+                        f"fit remaining capacity ({committed} of "
+                        f"{limit:.0f} already committed by residency + "
+                        "admitted budgets)")
+        return None
+
+    def _reserve_locked(self, sub: _Submission):
+        """Take a run slot for ``sub``, and reserve its declared budget
+        when the store has a capacity ceiling. Caller holds
+        ``_runs_lock``; ``_release_locked`` undoes it."""
+        if sub.declared and self.mdss.capacity_bytes:
+            self._reserved[sub.run_id] = (sub.ns, sub.declared)
+        self._live += 1
+
+    def _release_locked(self, run_id: str):
+        """Return a run's slot and reservation. Caller holds
+        ``_runs_lock``."""
+        self._reserved.pop(run_id, None)
+        self._live -= 1
 
     def _drain_parked(self):
         """Driver-side: admit parked submissions oldest-deadline-first
@@ -933,54 +903,32 @@ class EmeraldRuntime:
                 if not self._parked:
                     return
                 p = min(self._parked, key=_park_order)
-                if not self._fits_locked(p.declared):
+                if self._refusal_locked(p.sub.declared) is not None:
                     return
                 self._parked.remove(p)
-                if p.declared and self.mdss.capacity_bytes:
-                    self._reserved[p.run_id] = (p.ns, p.declared)
-                self._live += 1
+                self._reserve_locked(p.sub)
                 depth = len(self._parked)
+            p.handle._parked = False
+            waited = time.perf_counter() - p.parked_t
             try:
-                self._admit_parked(p, depth)
+                run = self._admit(p.sub, p.handle)
             except BaseException as e:
-                # symmetric release: an admission that fails mid-flight
-                # must return its reservation + run slot, exactly like
-                # the direct-submit reject path
                 with self._runs_lock:
-                    self._reserved.pop(p.run_id, None)
-                    self._live -= 1
-                p.handle._parked = False
+                    self._release_locked(p.sub.run_id)
                 p.handle._finish(error=e)
-
-    def _admit_parked(self, p: _Parked, depth: int):
-        """Turn one parked entry into a live run (driver thread)."""
-        if p.residency_budget:
-            for tier_name, max_bytes in p.residency_budget.items():
-                self.mdss.set_namespace_budget(p.ns, tier_name, max_bytes)
-        waited = time.perf_counter() - p.parked_t
-        try:
-            run = self._materialize(
-                p.pwf, p.wf, p.run_id, p.ns, p.mdss, p.init_vars, p.resume,
-                p.policy, p.fetch, p.weight, p.priority, p.speculate_after,
-                p.prefetch, p.checkpointer, p.handle, p.handle.events,
-                p.slo_ms, p.deadline_perf)
-        except BaseException:
-            for tier_name in (p.residency_budget or ()):
-                self.mdss.set_namespace_budget(p.ns, tier_name, None)
-            raise
-        run.preempt_fired = p.preempt_fired
-        # waited seconds become a fair-share deficit credit when the
-        # driver processes the submit message — a near-SLO latecomer
-        # overtakes tenants that were running while it was parked
-        run.admit_credit = waited
-        p.handle._parked = False
-        self.admitted_total += 1
-        self.metrics.inc("frontdoor.admitted_total")
-        self.metrics.observe("frontdoor.park_wait_s", waited)
-        info = {"waited_s": waited, "depth": depth}
-        if p.deadline_perf is not None:
-            info["slack_s"] = p.deadline_perf - time.perf_counter()
-        run.emit("admit", "<workflow>", **info)
+                continue
+            run.preempt_fired = p.preempt_fired
+            # waited seconds become a fair-share deficit credit when the
+            # driver processes the submit message — a near-SLO latecomer
+            # overtakes tenants that were running while it was parked
+            run.admit_credit = waited
+            self.admitted_total += 1
+            self.metrics.inc("frontdoor.admitted_total")
+            self.metrics.observe("frontdoor.park_wait_s", waited)
+            info = {"waited_s": waited, "depth": depth}
+            if p.sub.deadline_perf is not None:
+                info["slack_s"] = p.sub.deadline_perf - time.perf_counter()
+            run.emit("admit", "<workflow>", **info)
 
     def _fail_parked(self, err: BaseException):
         """Fail every parked entry (shutdown paths). Idempotent and
@@ -988,7 +936,6 @@ class EmeraldRuntime:
         with self._runs_lock:
             doomed, self._parked = self._parked, []
         for p in doomed:
-            p.handle._parked = False
             p.handle._finish(error=err)
 
     def _check_slo(self):
@@ -1004,21 +951,17 @@ class EmeraldRuntime:
             return
         now = time.perf_counter()
         threatened: List[Any] = []
+        # (entry that records the preemption, what carries its deadline)
         with self._runs_lock:
-            for p in self._parked:
-                if p.deadline_perf is None or p.preempt_fired:
-                    continue
-                if p.deadline_perf - now <= (p.slo_ms or 0.0) / 1000.0:
-                    p.preempt_fired = True
-                    threatened.append((p.handle.events, p.deadline_perf))
-        for run in self._runs.values():
-            if run.deadline_perf is None or run.preempt_fired:
+            waiting = [(p, p.sub) for p in self._parked]
+        waiting += [(run, run.sub) for run in self._runs.values()
+                    if run.ready[True] or run.ready[False]]   # lane-starved
+        for entry, sub in waiting:
+            if sub.deadline_perf is None or entry.preempt_fired:
                 continue
-            if not run.ready[True] and not run.ready[False]:
-                continue        # nothing waiting on a lane
-            if run.deadline_perf - now <= (run.slo_ms or 0.0) / 1000.0:
-                run.preempt_fired = True
-                threatened.append((run.events, run.deadline_perf))
+            if sub.deadline_perf - now <= (sub.slo_ms or 0.0) / 1000.0:
+                entry.preempt_fired = True
+                threatened.append((sub.events, sub.deadline_perf))
         for sink, deadline in threatened:
             task = broker.preempt_longest()
             if task is None:
@@ -1127,12 +1070,12 @@ class EmeraldRuntime:
         with self._runs_lock:
             runs = list(self._runs.values())
             parked_rows = [{
-                "run_id": p.run_id,
+                "run_id": p.sub.run_id,
                 "reason": p.reason,
                 "waited_s": now - p.parked_t,
-                "slack_s": (p.deadline_perf - now)
-                if p.deadline_perf is not None else None,
-                "slo_ms": p.slo_ms,
+                "slack_s": (p.sub.deadline_perf - now)
+                if p.sub.deadline_perf is not None else None,
+                "slo_ms": p.sub.slo_ms,
             } for p in sorted(self._parked, key=_park_order)]
         run_rows = []
         for run in runs:
@@ -1161,8 +1104,8 @@ class EmeraldRuntime:
                 "pending": sum(1 for st in states.values()
                                if st == "pending"),
                 "retries": run.retries,
-                "weight": run.weight,
-                "priority": run.priority,
+                "weight": run.sub.weight,
+                "priority": run.sub.priority,
                 "steps": states,
                 "placements": dict(run.placed),
                 "fair_share_vtime": self._fair.share_of(run.run_id),
@@ -1266,8 +1209,7 @@ class EmeraldRuntime:
                 return
             if msg[0] == "submit":
                 with self._runs_lock:
-                    self._reserved.pop(getattr(msg[1], "run_id", None), None)
-                    self._live -= 1
+                    self._release_locked(getattr(msg[1], "run_id", None))
                 msg[1].handle._finish(error=RuntimeClosed("runtime closed"))
             elif msg[0] == "introspect":
                 # answer directly so a caller racing close() never hangs
@@ -1313,13 +1255,12 @@ class EmeraldRuntime:
             run = msg[1]
             if self._draining:
                 with self._runs_lock:
-                    self._reserved.pop(run.run_id, None)
-                    self._live -= 1
+                    self._release_locked(run.run_id)
                 run.handle._finish(error=RuntimeClosed("runtime closed"))
                 return False
             with self._runs_lock:
                 self._runs[run.run_id] = run
-            self._fair.add(run.run_id, run.weight)
+            self._fair.add(run.run_id, run.sub.weight)
             if run.admit_credit:
                 # the park wait becomes deficit: vtime drops below the
                 # field, so the admitted run is picked first until the
@@ -1354,13 +1295,12 @@ class EmeraldRuntime:
                 # whole rollback
                 with self._runs_lock:
                     p = next((q for q in self._parked
-                              if q.run_id == msg[1]), None)
+                              if q.sub.run_id == msg[1]), None)
                     if p is not None:
                         self._parked.remove(p)
                 if p is not None:
-                    p.handle._parked = False
                     p.handle._finish(error=RunCancelled(
-                        f"run {p.run_id} cancelled"))
+                        f"run {p.sub.run_id} cancelled"))
         elif kind == "introspect":
             # built here, between mutations — serially consistent
             msg[1]["snapshot"] = self._introspect_unsafe()
@@ -1582,8 +1522,7 @@ class EmeraldRuntime:
     def _finalize(self, run: _Run, error: Optional[BaseException]):
         with self._runs_lock:
             del self._runs[run.run_id]
-            self._reserved.pop(run.run_id, None)
-            self._live -= 1
+            self._release_locked(run.run_id)
         self._fair.remove(run.run_id)
         self.runs_completed += 1
         if run.root_ctx is not None:
@@ -1606,7 +1545,7 @@ class EmeraldRuntime:
                 with self.tracer.span("reintegrate", cat="data",
                                       track="finalize", trace_id=run.run_id,
                                       parent=run.root_ctx):
-                    uris = run.fetch if run.fetch is not None else [
+                    uris = run.sub.fetch if run.sub.fetch is not None else [
                         u for u in run.wf.variables if run.mdss.version(u)]
                     result = {uri: run.mdss.get(uri, "local") for uri in uris
                               if run.mdss.version(uri)}
@@ -1648,7 +1587,7 @@ class EmeraldRuntime:
 
     def _run_local(self, run: _Run, s: Step):
         rep = self.manager.execute(s, "local", mdss=run.mdss,
-                                   priority=run.priority)
+                                   priority=run.sub.priority)
         run.emit("local", s.name, "local", seconds=rep.seconds,
                  memo_hit=rep.memo_hit)
 
@@ -1676,10 +1615,10 @@ class EmeraldRuntime:
     def _execute_maybe_speculative(self, run: _Run, s: Step, tier: str):
         alt = self._alternate_tier(s, tier)
         est = self.manager.cost_model.stats_for(s.name).measured_s.get(tier)
-        if run.speculate_after is None or alt is None or est is None:
+        if run.sub.speculate_after is None or alt is None or est is None:
             return self.manager.execute(s, tier, mdss=run.mdss,
-                                        priority=run.priority)
-        timeout = est * run.speculate_after
+                                        priority=run.sub.priority)
+        timeout = est * run.sub.speculate_after
         # no context manager: pool shutdown must NOT join the straggler
         spool = ThreadPoolExecutor(max_workers=2)
         # speculation twins run on fresh threads: re-attach the lane
@@ -1689,7 +1628,7 @@ class EmeraldRuntime:
         def execute(t, memo=None):
             with self.tracer.attach(ctx):
                 return self.manager.execute(s, t, mdss=run.mdss,
-                                            priority=run.priority,
+                                            priority=run.sub.priority,
                                             memoize=memo)
         try:
             primary = spool.submit(execute, tier)
@@ -1746,7 +1685,7 @@ class EmeraldRuntime:
         best-effort and version-hazard-checked), so the transfer safely
         overlaps this step's compute.
         """
-        if not run.prefetch or self.cloud_tier not in self.manager.tiers:
+        if not run.sub.prefetch or self.cloud_tier not in self.manager.tiers:
             return
         for m in run.succs.get(s.name, ()):
             succ = run.wf.steps[m]

@@ -118,6 +118,46 @@ def test_park_validation_runs_before_queueing():
             assert not rt._reserved and rt._live == 0
 
 
+@pytest.mark.parametrize("path", ["direct", "parked"])
+def test_submission_options_reach_the_run_on_either_path(path):
+    """Every per-submission option reaches the materialised run unchanged,
+    whether the run is admitted at once or parked and drained later."""
+    wf = Workflow("opts")
+    wf.var("x")
+    wf.step("s", lambda x: {"y": np.float64(x + 1.0), "z": np.float64(x)},
+            inputs=("x",), outputs=("y", "z"), remotable=False,
+            jax_step=False)
+    with EmeraldRuntime(emerald(), max_workers=2, max_active_runs=1,
+                        telemetry=False) as rt:
+        runs = []
+        materialize = rt._materialize
+
+        def spy(*a, **kw):
+            run = materialize(*a, **kw)
+            runs.append(run)
+            return run
+
+        rt._materialize = spy
+        head = rt.submit(sleeper_wf("head", 0.2), {"x": 0.0}) \
+            if path == "parked" else None
+        t0 = time.perf_counter()
+        h = rt.submit(wf, {"x": 1.0}, fetch=["y"], weight=2.5, priority=3,
+                      speculate_after=4.0, prefetch=False, park=True,
+                      deadline_s=30.0, slo_ms=250.0)
+        t1 = time.perf_counter()
+        assert (h.state == "parked") == (path == "parked")
+        assert h.result(10) == {"y": 2.0}
+        if head is not None:
+            head.result(10)
+        assert any(e.kind == "park" for e in h.events) == (path == "parked")
+        (sub,) = [r.sub for r in runs if r.run_id == h.run_id]
+        assert sub.fetch == ["y"]
+        assert (sub.weight, sub.priority) == (2.5, 3)
+        assert sub.speculate_after == 4.0 and sub.prefetch is False
+        assert sub.slo_ms == 250.0
+        assert t0 + 30.0 <= sub.deadline_perf <= t1 + 30.0
+
+
 def test_cancel_while_parked():
     with EmeraldRuntime(emerald(), max_workers=2, max_active_runs=1,
                         telemetry=False) as rt:

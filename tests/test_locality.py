@@ -244,9 +244,56 @@ def test_submit_residency_budget_bounds_run_namespace():
         with pytest.raises(ValueError, match="namespaced"):
             rt.submit(wf, {}, namespace="", residency_budget={"cloud": 1})
         # local is the write-back tier: a budget there would silently
-        # never evict, so it is rejected up front
+        # never evict, so it is rejected up front (x is provided: the
+        # verifier runs first and would reject a workflow missing it)
         with pytest.raises(ValueError, match="write-back"):
-            rt.submit(wf, {}, residency_budget={"local": 1})
+            rt.submit(wf, {"x": np.float64(0.0)},
+                      residency_budget={"local": 1})
+
+
+def _join_evictors():
+    for t in threading.enumerate():
+        if t.name == "mdss-evict":
+            t.join(5)
+
+
+def test_rejected_submission_sets_no_budget_and_evicts_nothing(monkeypatch):
+    """The verifier runs before a submission's namespace budgets land, so
+    a warm resubmission it rejects cannot evict that namespace's data."""
+    from repro.analysis import WorkflowRejected
+    from repro.analysis import verifier
+    mgr = emerald()
+    mdss = mgr.mdss
+    arr = np.ones(1024, np.float64)
+    for name in ("a", "b"):
+        mdss.put(f"warm/{name}", arr, tier="cloud")
+    cloud_before = mdss.namespace_tier_bytes("warm", "cloud")
+    resident_before = mdss.namespace_resident_bytes("warm")
+    real_verify = verifier.verify
+
+    def verify_after_evictions(*a, **kw):
+        # any eviction the submission has already started lands before
+        # the verdict, so the outcome does not depend on thread timing
+        _join_evictors()
+        return real_verify(*a, **kw)
+
+    monkeypatch.setattr(verifier, "verify", verify_after_evictions)
+    bad = Workflow("bad")
+    bad.var("a")
+    bad.var("missing")              # declared but never provided: W002
+    bad.step("s", lambda a, missing: {"y": a}, inputs=("a", "missing"),
+             outputs=("y",), jax_step=False)
+    with EmeraldRuntime(mgr, telemetry=False) as rt:
+        with pytest.raises(WorkflowRejected):
+            rt.submit(bad, {}, namespace="warm",
+                      residency_budget={"cloud": arr.nbytes // 2})
+        _join_evictors()
+        assert mdss.namespace_budget("warm", "cloud") is None
+        assert mdss.namespace_tier_bytes("warm", "cloud") == cloud_before
+        assert mdss.namespace_resident_bytes("warm") == resident_before
+        assert mdss.evictions == 0
+        with rt._runs_lock:
+            assert not rt._reserved and rt._live == 0
 
 
 def test_admission_control_refuses_near_capacity():
